@@ -39,6 +39,7 @@ from repro.fl.engine import FLEngine
 from repro.fl.faults import FaultPlan
 from repro.fl.nets import make_mlp
 from repro.fl.tasks import make_cfl_task, make_mask_task
+from repro import compile_cache
 
 N_CLIENTS = 4
 PLAN = FaultPlan(drop_rate=0.3, straggler_rate=0.1, corrupt_rate=0.2,
@@ -111,6 +112,7 @@ def smoke_scheme(name, task, factory, shards, theta0, *, rounds):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=6)
     ap.add_argument("--out", default="fault_events.json")

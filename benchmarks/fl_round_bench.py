@@ -61,6 +61,7 @@ from repro.fl.engine import FLEngine
 from repro.fl.faults import FaultPlan
 from repro.fl.nets import make_mlp
 from repro.fl.tasks import make_cfl_task, make_mask_task
+from repro import compile_cache
 
 
 def build_setup(fast: bool):
@@ -182,6 +183,7 @@ def bench_scheme(name, task, spec_factory, shards, theta0, *, rounds,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=None)
     ap.add_argument("--fast", action="store_true")

@@ -8,6 +8,7 @@ import argparse
 import json
 
 from repro.launch import dryrun  # noqa: F401  (sets XLA_FLAGS first)
+from repro import compile_cache
 
 
 def parse_overrides(items):
@@ -25,6 +26,7 @@ def parse_overrides(items):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

@@ -9,7 +9,8 @@
   ablation_nis   Appendix J.5: importance samples n_IS.
   ablation_block Appendix J.4: block size d/B.
   ablation_nclients  Appendix J.1: number of clients.
-  kernel_micro   Pallas kernel (interpret) vs jnp oracle timing + allclose.
+  kernel_micro   Pallas kernel vs jnp oracle timing + allclose (interpret
+                 mode on the CPU, compiled elsewhere).
   wire_audit     bytes on the wire per scheme: short wire-audited host runs
                  over the full registry matrix (stream bytes per round,
                  payload vs framing split; reconcile runs inside).
@@ -35,6 +36,7 @@ from repro.fl.data import make_synthetic, partition_dirichlet, partition_iid
 from repro.fl.engine import FLEngine
 from repro.fl.nets import make_cnn, make_mlp
 from repro.fl.tasks import make_cfl_task, make_mask_task
+from repro import compile_cache
 
 SEP = "-" * 100
 
@@ -188,8 +190,9 @@ def ablation_nclients(fast: bool):
 
 
 def kernel_micro(fast: bool):
-    print("\n== kernel microbench: mrc_logw / bernoulli_kl (interpret) vs jnp ==")
     from repro.kernels import ops, ref
+    mode = "interpret" if ops.resolve_interpret(None) else "compiled"
+    print(f"\n== kernel microbench: mrc_logw / bernoulli_kl ({mode}) vs jnp ==")
     key = jax.random.PRNGKey(0)
     nb, nis, s = (8, 256, 256)
     x = (jax.random.uniform(key, (nb, nis, s)) < 0.5).astype(jnp.float32)
@@ -209,7 +212,7 @@ def kernel_micro(fast: bool):
     t_pal, o_pal = bench(lambda *z: ops.mrc_logw(*z), x, a, b)
     err = float(jnp.max(jnp.abs(o_ref - o_pal)))
     print(f"mrc_logw ({nb}x{nis}x{s}):  jnp={t_ref:9.1f}us  "
-          f"pallas(interpret)={t_pal:9.1f}us  max_err={err:.2e}")
+          f"pallas({mode})={t_pal:9.1f}us  max_err={err:.2e}")
     q = jax.random.uniform(key, (64, 256), minval=0.05, maxval=0.95)
     p = jax.random.uniform(jax.random.fold_in(key, 3), (64, 256),
                            minval=0.05, maxval=0.95)
@@ -217,9 +220,10 @@ def kernel_micro(fast: bool):
     t_pal, o_pal = bench(lambda *z: ops.bernoulli_kl(*z), q, p)
     err = float(jnp.max(jnp.abs(o_ref - o_pal)))
     print(f"bernoulli_kl (64x256):  jnp={t_ref:9.1f}us  "
-          f"pallas(interpret)={t_pal:9.1f}us  max_err={err:.2e}")
-    print("(interpret mode runs the kernel body in Python -- correctness "
-          "check; TPU timing requires hardware)")
+          f"pallas({mode})={t_pal:9.1f}us  max_err={err:.2e}")
+    if mode == "interpret":
+        print("(interpret mode runs the kernel body in Python -- correctness "
+              "check; TPU timing requires hardware)")
 
 
 def wire_audit(fast: bool):
@@ -306,6 +310,7 @@ BENCHES = {
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(BENCHES))
     ap.add_argument("--fast", action="store_true")

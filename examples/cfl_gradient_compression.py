@@ -17,9 +17,11 @@ from repro.fl.data import make_synthetic, partition_iid
 from repro.fl.federator import CFLConfig, run_bicompfl_cfl
 from repro.fl.nets import make_mlp
 from repro.fl.tasks import make_cfl_task
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     key = jax.random.PRNGKey(0)
     train, test = make_synthetic(key, n_train=2000, n_test=500, hw=10, noise=0.4)
     shards = partition_iid(jax.random.fold_in(key, 1), train, 10, 200)

@@ -10,9 +10,11 @@ import json
 
 # must run before any other jax-touching import (device-count lock-in)
 from repro.launch import dryrun  # noqa: F401  (sets XLA_FLAGS at import)
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="jamba-v0.1-52b")
     ap.add_argument("--shape", default="decode_32k")

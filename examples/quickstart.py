@@ -17,9 +17,11 @@ from repro.fl.engine import FLEngine
 from repro.fl.registry import bicompfl_spec
 from repro.fl.nets import make_mlp
 from repro.fl.tasks import make_mask_task
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     key = jax.random.PRNGKey(0)
     train, test = make_synthetic(key, n_train=2000, n_test=500, hw=10, noise=0.4)
     n_clients = 10

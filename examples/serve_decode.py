@@ -13,9 +13,11 @@ import numpy as np
 
 import repro.configs as C
 from repro.launch.serve import Request, Server
+from repro import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=list(C.ALIASES))
     ap.add_argument("--batch", type=int, default=4)

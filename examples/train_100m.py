@@ -22,6 +22,7 @@ from repro.data import batches_for
 from repro.launch.mesh import make_host_mesh
 from repro.launch.train import Trainer
 from repro.models.config import ArchConfig
+from repro import compile_cache
 
 CFG_100M = ArchConfig(
     name="repro-100m", arch_type="dense",
@@ -33,6 +34,7 @@ CFG_100M = ArchConfig(
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

@@ -99,26 +99,23 @@ def _kl_stats(payload, priors, *, needs_profile: bool) -> Dict[str, Any]:
     Mirrors the host loop's profile (per-parameter KL of the posterior
     against the client priors, averaged over the active cohort) without
     leaving the device.  On a real accelerator backend both allocation
-    flavours run through the Pallas ``bernoulli_kl`` streaming reduction:
-    the *mean*-only consumers (``needs_profile=False``,
+    flavours run through the compiled Pallas ``bernoulli_kl`` streaming
+    reduction: the *mean*-only consumers (``needs_profile=False``,
     e.g. AdaptiveAvgAllocation) take
     ``repro.kernels.ops.bernoulli_kl_total``, and the full-profile
     consumers (``needs_profile=True``, AdaptiveAllocation) take
-    ``repro.kernels.ops.bernoulli_kl_profile`` (parameters as kernel
-    blocks, clients streaming through the reduction).  In interpret mode
-    (CPU) the kernel emulation is orders of magnitude slower than the
-    fused XLA elementwise reduction, so the jnp route is used there (the
-    kernels' repo-wide convention: interpret=True exists to *validate* on
-    CPU, not to run hot loops).  Both routes agree up to f32 summation
-    order.
+    ``repro.kernels.ops.bernoulli_kl_profile`` (clients as the kernel's
+    reduction axis).  On the CPU the kernels could only run in the Pallas
+    interpreter, orders of magnitude slower than the fused XLA elementwise
+    reduction, so the jnp route is used there.  Both routes agree up to
+    f32 summation order.
     """
     p = clip01(priors)
     if jax.default_backend() != "cpu":
         if needs_profile:
-            klp = bernoulli_kl_profile(payload, p, interpret=False)
+            klp = bernoulli_kl_profile(payload, p)
             return {"profile": klp, "total": jnp.sum(klp)}
-        return {"profile": None,
-                "total": bernoulli_kl_total(payload, p, interpret=False)}
+        return {"profile": None, "total": bernoulli_kl_total(payload, p)}
     klp = jnp.mean(jax.vmap(bern_kl)(payload, p), axis=0)
     return {"profile": klp if needs_profile else None,
             "total": jnp.sum(klp)}

@@ -75,7 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "skv", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool, window: int, scale: float,
-                           skv: int, interpret: bool = True) -> jax.Array:
+                           skv: int, interpret: bool) -> jax.Array:
     """q: (BH, Sq, Dh); k, v: (BH, Skv_pad, Dh); 128-aligned shapes.
 
     ``skv`` is the unpadded kv length (mask boundary).  Use

@@ -73,7 +73,7 @@ def _rwkv_chunk_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def rwkv_chunk_pallas(r: jax.Array, k: jax.Array, v: jax.Array,
                       logw: jax.Array, u: jax.Array, *,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """r/k/v/logw: (BH, S, Dh) with S % CHUNK == 0; u: (BH, 1, Dh).
 
     Returns the time-mix output (BH, S, Dh); zero initial state.  Use

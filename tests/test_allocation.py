@@ -17,10 +17,7 @@ Pinned properties:
 """
 import math
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: deterministic fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 import numpy as np

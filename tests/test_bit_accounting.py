@@ -18,10 +18,7 @@ bookkeeping per direction, so the accounting invariants are pinned here for
 """
 import math
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: deterministic fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

@@ -11,10 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: deterministic fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import mrc
 from repro.core.bernoulli import bern_kl, clip01, log_ratio_coeffs
@@ -71,6 +68,24 @@ class TestKernelParity:
     def test_all_singletons(self):
         d = 40
         _assert_parity(12, d, jnp.arange(d, dtype=jnp.int32), d, seed=2)
+
+    @pytest.mark.parametrize("d, lengths", [
+        # many short segments: one d-tile spans several segment windows
+        (3 * TILE_D + 70, None),
+        # few long segments: one window spans several d-tiles
+        (3 * TILE_D + 70, [TILE_D + 5, 2 * TILE_D, 65]),
+        # empty segments: ids jump past whole windows mid d-tile
+        (2 * TILE_D, [300, 0, 0, 112] + [0] * (2 * NSEG_LANE) + [612]),
+    ])
+    def test_staircase_over_tiles_and_windows(self, d, lengths):
+        if lengths is None:
+            seg = np.arange(d) // 3
+        else:
+            assert sum(lengths) == d
+            seg = np.repeat(np.arange(len(lengths)), lengths)
+        n_seg = int(seg[-1]) + 1
+        assert n_seg > NSEG_LANE or d > 2 * TILE_D
+        _assert_parity(8, d, jnp.asarray(seg, jnp.int32), n_seg, seed=4)
 
     def test_tile_aligned_no_padding(self):
         # exercise the raw kernel entry point without the ops padding wrapper

@@ -27,10 +27,7 @@ import pathlib
 import re
 from types import SimpleNamespace
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container has no hypothesis: deterministic fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
