@@ -124,16 +124,19 @@ def encode_fixed(
         q = jnp.concatenate([q, halfq])
         p = jnp.concatenate([p, halfq])
 
-    a, b = log_ratio_coeffs(q, p)  # (B', S) each
+    with jax.named_scope("mrc.logw"):
+        a, b = log_ratio_coeffs(q, p)  # (B', S) each
 
     def chunk_body(c):
         block_ids = c * nb + jnp.arange(nb)
         pc = jax.lax.dynamic_slice_in_dim(p, c * nb, nb, axis=0)  # (nb, S)
         ac = jax.lax.dynamic_slice_in_dim(a, c * nb, nb, axis=0)
         bc = jax.lax.dynamic_slice_in_dim(b, c * nb, nb, axis=0)
-        u = jax.vmap(lambda bid: _block_candidates(shared_key, bid, n_is, S))(block_ids)
-        x = (u < clip01(pc)[:, None, :]).astype(jnp.float32)
-        logw = logw_impl(x, ac, bc)  # (nb, n_is)
+        with jax.named_scope("mrc.draw"):
+            u = jax.vmap(lambda bid: _block_candidates(shared_key, bid, n_is, S))(block_ids)
+            x = (u < clip01(pc)[:, None, :]).astype(jnp.float32)
+        with jax.named_scope("mrc.logw"):
+            logw = logw_impl(x, ac, bc)  # (nb, n_is)
         gu = jax.vmap(
             lambda bid: jax.random.uniform(jax.random.fold_in(select_key, bid), (n_is,))
         )(block_ids)
@@ -154,8 +157,9 @@ def decode_fixed(shared_key: jax.Array, indices: jax.Array, p: jax.Array, *, n_i
     B, S = p.shape
 
     def per_block(bid, idx, pb):
-        u = _selected_candidate(shared_key, bid, idx, n_is, S)
-        return (u < clip01(pb)).astype(jnp.float32)
+        with jax.named_scope("mrc.draw"):
+            u = _selected_candidate(shared_key, bid, idx, n_is, S)
+            return (u < clip01(pb)).astype(jnp.float32)
 
     return jax.vmap(per_block)(jnp.arange(B), indices, p)
 
@@ -262,9 +266,11 @@ def _encode_segments(
 ) -> MRCResult:
     logw_impl = seg_logw_fn if seg_logw_fn is not None else default_segment_logw
     pc = clip01(p)
-    u = _segment_candidates(shared_key, n_is, q.shape[0])       # (n_is, d)
-    a, b = log_ratio_coeffs(q, p)                               # (d,), (d,)
-    logw = logw_impl(u, pc, a, b, seg_ids, n_seg)               # (n_is, n_seg)
+    with jax.named_scope("mrc.draw"):
+        u = _segment_candidates(shared_key, n_is, q.shape[0])   # (n_is, d)
+    with jax.named_scope("mrc.logw"):
+        a, b = log_ratio_coeffs(q, p)                           # (d,), (d,)
+        logw = logw_impl(u, pc, a, b, seg_ids, n_seg)           # (n_is, n_seg)
     gu = jax.random.uniform(select_key, (n_is, n_seg))
     gumbel = -jnp.log(-jnp.log(jnp.clip(gu, 1e-12, 1.0 - 1e-12)))
     idx = jnp.argmax(logw + gumbel, axis=0).astype(jnp.int32)   # (n_seg,)
@@ -313,9 +319,10 @@ def _decode_segments(
     shared_key: jax.Array, indices: jax.Array, p: jax.Array, seg_ids: jax.Array, *, n_is: int
 ) -> jax.Array:
     d = p.shape[0]
-    u = _segment_candidates(shared_key, n_is, d)
-    u_sel = jnp.take_along_axis(u, indices[seg_ids][None, :], axis=0)[0]
-    return (u_sel < clip01(p)).astype(jnp.float32)
+    with jax.named_scope("mrc.draw"):
+        u = _segment_candidates(shared_key, n_is, d)
+        u_sel = jnp.take_along_axis(u, indices[seg_ids][None, :], axis=0)[0]
+        return (u_sel < clip01(p)).astype(jnp.float32)
 
 
 def decode_segments(

@@ -70,6 +70,24 @@ run a pure function of ``seed`` with no host RNG.
 
 The engine reproduces the seed loops bit-for-bit at full participation
 (tests/test_engine_parity.py); see DESIGN.md for the API contract.
+
+Tracing: every stage of a round runs under a ``jax.named_scope``, which a
+profiler trace shows in each device op's name stack: ``fl.train`` (round
+and training keys, the cohort gather, local training and its pin),
+``fl.control`` (the adaptive KL statistics and bucket choice),
+``fl.uplink``, ``fl.aggregate``, ``fl.downlink`` (each with its pins),
+``fl.flush``, ``fl.faults`` and ``fl.eval``; ``core/mrc.py`` and
+``fl/tasks.py`` add ``mrc.draw``, ``mrc.logw`` and ``local.batch`` inside
+them.  Scopes are HLO metadata: the compiled program is the same without
+them.  On the host, ``jax.profiler.TraceAnnotation`` spans mark
+``fl.prepare``, ``fl.dispatch`` (the compiled program's call),
+``fl.fetch`` (the first read of its outputs, which waits for the device),
+``fl.book`` and ``fl.checkpoint``; they cost nothing measurable while no
+trace is being taken.  JAX's persistent compilation cache leaves metadata
+out of its key: a program that differs from a cached one only in its
+scopes is served the cached executable, whose trace shows the old names.
+So a change of scopes alone also renames the jitted program
+(``fl_rounds``, formerly ``run_fn``).
 """
 from __future__ import annotations
 
@@ -331,20 +349,25 @@ class FLEngine:
         Every cross-stage value is pinned through ``channels.pin`` (an
         integer-space round-trip on a traced zero) so XLA cannot
         FMA-contract across stage boundaries and break host/fused
-        bit-parity.
+        bit-parity.  Each stage, its pins included, runs under its
+        ``fl.*`` name scope (see the module docstring's Tracing).
         """
         pp = ctx.pin_token
-        up_out, ul_bits, up_s = spec.uplink.step_up(
-            ctx, up_s, payload, priors)
-        up_out, up_s = pin(pp, (up_out, up_s))
-        update = spec.aggregator(ctx, theta, up_out)
-        update = ServerUpdate(theta=pin(pp, update.theta),
-                              delta=pin(pp, update.delta)
-                              if update.delta is not None else None,
-                              lr=update.lr)
-        res, dn_s = spec.downlink.step_down(
-            ctx, dn_s, update, theta, theta_hat)
-        theta, theta_hat, dn_s = pin(pp, (res.theta, res.theta_hat, dn_s))
+        with jax.named_scope("fl.uplink"):
+            up_out, ul_bits, up_s = spec.uplink.step_up(
+                ctx, up_s, payload, priors)
+            up_out, up_s = pin(pp, (up_out, up_s))
+        with jax.named_scope("fl.aggregate"):
+            update = spec.aggregator(ctx, theta, up_out)
+            update = ServerUpdate(theta=pin(pp, update.theta),
+                                  delta=pin(pp, update.delta)
+                                  if update.delta is not None else None,
+                                  lr=update.lr)
+        with jax.named_scope("fl.downlink"):
+            res, dn_s = spec.downlink.step_down(
+                ctx, dn_s, update, theta, theta_hat)
+            theta, theta_hat, dn_s = pin(pp, (res.theta, res.theta_hat,
+                                              dn_s))
         oh = plan.overhead_bits * ctx.n_clients if plan is not None else 0.0
         return theta, theta_hat, up_s, dn_s, update, ul_bits, res.bits, oh
 
@@ -398,67 +421,72 @@ class FLEngine:
             raise ValueError(
                 f"spec {spec.name!r} cannot checkpoint/resume: channels "
                 "without the pure-state protocol have no explicit carry")
-        # Stateful channels (error-feedback memories) must start fresh: a
-        # spec may be run more than once.
-        for chan in (spec.uplink, spec.downlink):
-            reset = getattr(chan, "reset", None)
-            if reset is not None:
-                reset()
-        n = int(shards.x.shape[0])
-        theta = task.init_theta() if theta0 is None else theta0
-        d = int(theta.shape[0])
-        theta_hat = jnp.tile(theta[None], (n, 1))
-        meter = BitMeter(
-            n_clients=n, d=d,
-            broadcast_downlink_shareable=getattr(
-                spec.downlink, "broadcast_shareable", True))
-        n_active = max(1, int(round(spec.participation * n)))
-        schedule = self.cohort_schedule(rounds, n, n_active, seed, cohort_rng)
+        with jax.profiler.TraceAnnotation("fl.prepare"):
+            # Stateful channels (error-feedback memories) must start fresh: a
+            # spec may be run more than once.
+            for chan in (spec.uplink, spec.downlink):
+                reset = getattr(chan, "reset", None)
+                if reset is not None:
+                    reset()
+            n = int(shards.x.shape[0])
+            theta = task.init_theta() if theta0 is None else theta0
+            d = int(theta.shape[0])
+            theta_hat = jnp.tile(theta[None], (n, 1))
+            meter = BitMeter(
+                n_clients=n, d=d,
+                broadcast_downlink_shareable=getattr(
+                    spec.downlink, "broadcast_shareable", True))
+            n_active = max(1, int(round(spec.participation * n)))
+            schedule = self.cohort_schedule(rounds, n, n_active, seed,
+                                            cohort_rng)
 
-        # Fault schedule: precomputed like the cohort schedule, before any
-        # round work.  ``views`` stays None when the drawn schedule is
-        # fault-free, keeping the run on the exact legacy code paths.
-        fsched = views_all = views = None
-        if faults is not None:
-            fsched = faults.schedule(rounds, n)
-            dl_rec = getattr(spec.downlink, "downlink_recipients", "all")
-            views_all = fsched.run_views(schedule, dl_rec)
-            if any(v.faulty or v.all_failed for v in views_all):
-                views = views_all
-        if views is not None and not wire and not self._functional_channels():
-            raise ValueError(
-                f"spec {spec.name!r} cannot run under faults without the "
-                "pure-state channel protocol (state rows must be carried "
-                "explicitly) or a wire session")
-        if views is not None and wire:
-            for role, chan in (("uplink", spec.uplink),
-                               ("downlink", spec.downlink)):
-                if not (hasattr(chan, "export_state")
-                        and hasattr(chan, "import_state")):
-                    raise ValueError(
-                        f"spec {spec.name!r} cannot run faulted wire audit: "
-                        f"{role} channel lacks export_state/import_state")
+            # Fault schedule: precomputed like the cohort schedule, before
+            # any round work.  ``views`` stays None when the drawn schedule
+            # is fault-free, keeping the run on the exact legacy code paths.
+            fsched = views_all = views = None
+            if faults is not None:
+                fsched = faults.schedule(rounds, n)
+                dl_rec = getattr(spec.downlink, "downlink_recipients", "all")
+                views_all = fsched.run_views(schedule, dl_rec)
+                if any(v.faulty or v.all_failed for v in views_all):
+                    views = views_all
+            if (views is not None and not wire
+                    and not self._functional_channels()):
+                raise ValueError(
+                    f"spec {spec.name!r} cannot run under faults without the "
+                    "pure-state channel protocol (state rows must be carried "
+                    "explicitly) or a wire session")
+            if views is not None and wire:
+                for role, chan in (("uplink", spec.uplink),
+                                   ("downlink", spec.downlink)):
+                    if not (hasattr(chan, "export_state")
+                            and hasattr(chan, "import_state")):
+                        raise ValueError(
+                            f"spec {spec.name!r} cannot run faulted wire "
+                            f"audit: {role} channel lacks "
+                            "export_state/import_state")
 
-        if mode not in ("auto", "host", "fused"):
-            raise ValueError(mode)
-        fused_ok = self.fused_supported()
-        if mode == "fused" and not fused_ok:
-            raise ValueError(
-                f"spec {spec.name!r} needs the host control plane "
-                "(non-functional channels, an allocation without the bucket "
-                "API, or a data-dependent plan combined with an EF flush)")
-        fused = fused_ok and mode != "host" and not wire
+            if mode not in ("auto", "host", "fused"):
+                raise ValueError(mode)
+            fused_ok = self.fused_supported()
+            if mode == "fused" and not fused_ok:
+                raise ValueError(
+                    f"spec {spec.name!r} needs the host control plane "
+                    "(non-functional channels, an allocation without the "
+                    "bucket API, or a data-dependent plan combined with an "
+                    "EF flush)")
+            fused = fused_ok and mode != "host" and not wire
 
-        cfg_blob = None
-        if checkpoint_dir or resume_from:
-            cfg_blob = self._config_blob(rounds=rounds, seed=seed,
-                                         eval_every=eval_every,
-                                         cohort_rng=cohort_rng, n=n, d=d,
-                                         faults=faults)
-        start_round, carry_in, history0 = 0, None, None
-        if resume_from:
-            start_round, theta, theta_hat, carry_in, history0 = \
-                self._load_resume(resume_from, cfg_blob, meter)
+            cfg_blob = None
+            if checkpoint_dir or resume_from:
+                cfg_blob = self._config_blob(rounds=rounds, seed=seed,
+                                             eval_every=eval_every,
+                                             cohort_rng=cohort_rng, n=n, d=d,
+                                             faults=faults)
+            start_round, carry_in, history0 = 0, None, None
+            if resume_from:
+                start_round, theta, theta_hat, carry_in, history0 = \
+                    self._load_resume(resume_from, cfg_blob, meter)
 
         if fused:
             out = self._run_fused(shards, theta, theta_hat, meter,
@@ -779,8 +807,9 @@ class FLEngine:
             if staged and checkpoint_dir and (
                     (checkpoint_every and (t + 1) % checkpoint_every == 0)
                     or t + 1 == rounds):
-                self._save_state(checkpoint_dir, t + 1, theta, theta_hat,
-                                 up_s, dn_s, meter, history, cfg_blob)
+                with jax.profiler.TraceAnnotation("fl.checkpoint"):
+                    self._save_state(checkpoint_dir, t + 1, theta, theta_hat,
+                                     up_s, dn_s, meter, history, cfg_blob)
 
         return self._result(history, meter, theta, theta_hat)
 
@@ -1038,26 +1067,28 @@ class FLEngine:
         # as traced f32 per-round vectors instead.
         booked: Dict[str, Any] = {}
 
-        def run_fn(base, carry0, sx, sy, xs_all):
+        def fl_rounds(base, carry0, sx, sy, xs_all):
             self.fused_trace_count += 1  # Python side effect: trace-time only
 
             def body(carry, xs):
                 theta, theta_hat, up_s, dn_s = carry
                 prev = carry  # pre-round view: what faults carry forward
-                kt = mrc.round_key(base, xs["t"])
                 active = xs["active"]
                 pp = xs["pin"]  # traced int32 zero: the rounding pin token
                 w = xs["w"] if faulted else None
 
-                train_keys = jax.random.split(
-                    jax.random.fold_in(kt, TAG_TRAIN), n)
-                if full:
-                    priors, bx, by, keys = theta_hat, sx, sy, train_keys
-                else:
-                    priors = theta_hat[active]
-                    bx, by, keys = sx[active], sy[active], train_keys[active]
-                payload = pin(pp, jax.vmap(task.local_train)(
-                    priors, bx, by, keys))
+                with jax.named_scope("fl.train"):
+                    kt = mrc.round_key(base, xs["t"])
+                    train_keys = jax.random.split(
+                        jax.random.fold_in(kt, TAG_TRAIN), n)
+                    if full:
+                        priors, bx, by, keys = theta_hat, sx, sy, train_keys
+                    else:
+                        priors = theta_hat[active]
+                        bx, by, keys = (sx[active], sy[active],
+                                        train_keys[active])
+                    payload = pin(pp, jax.vmap(task.local_train)(
+                        priors, bx, by, keys))
 
                 def make_ctx(plan):
                     return RoundContext(t=xs["t"], key=kt, n_clients=n, d=d,
@@ -1065,15 +1096,18 @@ class FLEngine:
                                         pin_token=pp, up_weight=w)
 
                 if adaptive:
-                    stats = _kl_stats(payload, priors,
-                                      needs_profile=getattr(
-                                          alloc, "needs_profile", True))
-                    bidx = alloc.select_bucket(stats, d)
+                    with jax.named_scope("fl.control"):
+                        stats = _kl_stats(payload, priors,
+                                          needs_profile=getattr(
+                                              alloc, "needs_profile", True))
+                        bidx = alloc.select_bucket(stats, d)
 
                     def make_branch(template):
                         def branch(op):
                             th, thh, us, ds = op
-                            plan = alloc.finalize_plan(template, stats, d)
+                            with jax.named_scope("fl.control"):
+                                plan = alloc.finalize_plan(template, stats,
+                                                           d)
                             th, thh, us, ds, _, ulb, dlb, oh = \
                                 self._round_core(spec, plan, th, thh, us, ds,
                                                  payload, priors,
@@ -1100,13 +1134,14 @@ class FLEngine:
                     # that missed the downlink keep the pre-round value,
                     # EF rows of undelivered uplinks are carried, and the
                     # whole step is discarded on an all-fail round.
-                    theta_hat = jnp.where(xs["recv"][:, None], theta_hat,
-                                          prev[1])
-                    up_s = _carry_rows(prev[2], up_s, xs["keep_up"])
-                    ok = xs["ok"]
-                    theta, theta_hat, up_s, dn_s = jax.tree.map(
-                        lambda nw, od: jnp.where(ok, nw, od),
-                        (theta, theta_hat, up_s, dn_s), prev)
+                    with jax.named_scope("fl.faults"):
+                        theta_hat = jnp.where(xs["recv"][:, None],
+                                              theta_hat, prev[1])
+                        up_s = _carry_rows(prev[2], up_s, xs["keep_up"])
+                        ok = xs["ok"]
+                        theta, theta_hat, up_s, dn_s = jax.tree.map(
+                            lambda nw, od: jnp.where(ok, nw, od),
+                            (theta, theta_hat, up_s, dn_s), prev)
 
                 if not adaptive and spec.sync_period:
                     def do_flush(op):
@@ -1121,19 +1156,22 @@ class FLEngine:
                         return pin(pp, (th, jnp.tile(th[None], (n, 1)),
                                         us, ds))
 
-                    theta, theta_hat, up_s, dn_s = jax.lax.cond(
-                        xs["flush"], do_flush, lambda op: op,
-                        (theta, theta_hat, up_s, dn_s))
+                    with jax.named_scope("fl.flush"):
+                        theta, theta_hat, up_s, dn_s = jax.lax.cond(
+                            xs["flush"], do_flush, lambda op: op,
+                            (theta, theta_hat, up_s, dn_s))
 
-                acc = jax.lax.cond(
-                    xs["eval"],
-                    lambda th: jnp.asarray(task.evaluate(th), jnp.float32),
-                    lambda th: jnp.full((), jnp.nan, jnp.float32), theta)
+                with jax.named_scope("fl.eval"):
+                    acc = jax.lax.cond(
+                        xs["eval"],
+                        lambda th: jnp.asarray(task.evaluate(th),
+                                               jnp.float32),
+                        lambda th: jnp.full((), jnp.nan, jnp.float32), theta)
                 return (theta, theta_hat, up_s, dn_s), (acc,) + bits
 
             return jax.lax.scan(body, carry0, xs_all)
 
-        return jax.jit(run_fn), booked
+        return jax.jit(fl_rounds), booked
 
     def _run_fused(self, shards, theta, theta_hat, meter, *, rounds, seed,
                    eval_every, schedule, views=None, start_round=0,
@@ -1149,46 +1187,49 @@ class FLEngine:
         dl_rec = getattr(spec.downlink, "downlink_recipients", "all")
         dl_denom = n if dl_rec == "all" else n_active
 
-        eval_mask = np.zeros(rounds, bool)
-        eval_mask[eval_every - 1::eval_every] = True
-        if rounds:
-            eval_mask[-1] = True
-        flush_mask = np.zeros(rounds, bool)
-        if spec.sync_period:
-            flush_mask[spec.sync_period - 1::spec.sync_period] = True
+        with jax.profiler.TraceAnnotation("fl.prepare"):
+            eval_mask = np.zeros(rounds, bool)
+            eval_mask[eval_every - 1::eval_every] = True
+            if rounds:
+                eval_mask[-1] = True
+            flush_mask = np.zeros(rounds, bool)
+            if spec.sync_period:
+                flush_mask[spec.sync_period - 1::spec.sync_period] = True
 
-        if carry_in is not None:
-            up_s0, dn_s0 = carry_in
-        else:
-            up_s0 = spec.uplink.init_up_state(n, d)
-            dn_s0 = spec.downlink.init_down_state(n, d)
-        carry = (theta, theta_hat, up_s0, dn_s0)
+            if carry_in is not None:
+                up_s0, dn_s0 = carry_in
+            else:
+                up_s0 = spec.uplink.init_up_state(n, d)
+                dn_s0 = spec.downlink.init_down_state(n, d)
+            carry = (theta, theta_hat, up_s0, dn_s0)
 
-        xs_full = {"t": jnp.arange(rounds, dtype=jnp.int32),
-                   "active": jnp.asarray(schedule),
-                   "eval": jnp.asarray(eval_mask),
-                   "flush": jnp.asarray(flush_mask),
-                   "pin": jnp.zeros(rounds, jnp.int32)}
-        if faulted:
-            xs_full["w"] = jnp.asarray(
-                np.stack([v.up_weight for v in views]))
-            xs_full["keep_up"] = jnp.asarray(
-                np.stack([v.delivered_up for v in views]))
-            xs_full["recv"] = jnp.asarray(
-                np.stack([v.delivered_dn for v in views]))
-            xs_full["ok"] = jnp.asarray(
-                np.asarray([not v.all_failed for v in views]))
+            xs_full = {"t": jnp.arange(rounds, dtype=jnp.int32),
+                       "active": jnp.asarray(schedule),
+                       "eval": jnp.asarray(eval_mask),
+                       "flush": jnp.asarray(flush_mask),
+                       "pin": jnp.zeros(rounds, jnp.int32)}
+            if faulted:
+                xs_full["w"] = jnp.asarray(
+                    np.stack([v.up_weight for v in views]))
+                xs_full["keep_up"] = jnp.asarray(
+                    np.stack([v.delivered_up for v in views]))
+                xs_full["recv"] = jnp.asarray(
+                    np.stack([v.delivered_dn for v in views]))
+                xs_full["ok"] = jnp.asarray(
+                    np.asarray([not v.all_failed for v in views]))
 
-        # Checkpoint boundaries segment the scan: an uninterrupted
-        # checkpointed run and a killed-and-resumed one execute the same
-        # program sequence over the same carries, hence are bit-identical.
-        bounds = set()
-        if checkpoint_dir and checkpoint_every:
-            first = ((start_round // checkpoint_every) + 1) * checkpoint_every
-            bounds = set(range(first, rounds, checkpoint_every))
-        cuts = sorted(bounds | {rounds})
-        history = list(history) if history else []
-        base = jax.random.PRNGKey(seed)
+            # Checkpoint boundaries segment the scan: an uninterrupted
+            # checkpointed run and a killed-and-resumed one execute the
+            # same program sequence over the same carries, hence are
+            # bit-identical.
+            bounds = set()
+            if checkpoint_dir and checkpoint_every:
+                first = (start_round // checkpoint_every + 1) \
+                    * checkpoint_every
+                bounds = set(range(first, rounds, checkpoint_every))
+            cuts = sorted(bounds | {rounds})
+            history = list(history) if history else []
+            base = jax.random.PRNGKey(seed)
         s = start_round
         if s >= rounds:
             return self._result(history, meter, theta, theta_hat)
@@ -1196,97 +1237,106 @@ class FLEngine:
             if e <= s:
                 continue
             L = e - s
-            # One compiled program per segment signature: the seed, cohort
-            # schedule, fault tables and eval/flush masks ride in as
-            # *data*, so seed replicates and eval-cadence changes hit the
-            # cache; only a shape change (segment length, client count,
-            # model size, dataset shard dims, fault mode) builds a new
-            # program.
-            sig = (L, n, d, n_active, faulted,
-                   tuple(shards.x.shape), str(shards.x.dtype),
-                   tuple(shards.y.shape), str(shards.y.dtype),
-                   tuple(theta.shape), str(theta.dtype))
-            prog = self._fused_programs.get(sig)
-            if prog is None:
-                prog = self._build_fused(rounds=L, n=n, d=d,
-                                         n_active=n_active, faulted=faulted)
-                self._fused_programs[sig] = prog
-            fn, booked = prog
-            xs = {k: v[s:e] for k, v in xs_full.items()}
-            carry, outs = fn(base, carry, shards.x, shards.y, xs)
+            with jax.profiler.TraceAnnotation("fl.prepare"):
+                # One compiled program per segment signature: the seed,
+                # cohort schedule, fault tables and eval/flush masks ride
+                # in as *data*, so seed replicates and eval-cadence changes
+                # hit the cache; only a shape change (segment length,
+                # client count, model size, dataset shard dims, fault
+                # mode) builds a new program.
+                sig = (L, n, d, n_active, faulted,
+                       tuple(shards.x.shape), str(shards.x.dtype),
+                       tuple(shards.y.shape), str(shards.y.dtype),
+                       tuple(theta.shape), str(theta.dtype))
+                prog = self._fused_programs.get(sig)
+                if prog is None:
+                    prog = self._build_fused(rounds=L, n=n, d=d,
+                                             n_active=n_active,
+                                             faulted=faulted)
+                    self._fused_programs[sig] = prog
+                fn, booked = prog
+                xs = {k: v[s:e] for k, v in xs_full.items()}
+            with jax.profiler.TraceAnnotation("fl.dispatch"):
+                carry, outs = fn(base, carry, shards.x, shards.y, xs)
+            # The first host read of the outputs waits for the device.
+            with jax.profiler.TraceAnnotation("fl.fetch"):
+                outs = [np.asarray(o) for o in outs]
             seg_eval = eval_mask[s:e]
-
-            if adaptive:
-                # Traced-bits booking: the scan's stacked per-round bit
-                # totals are the only extra device->host transfer.  They
-                # are exact as long as they stay below 2**24 -- every term
-                # is an integer times log2 of a pow2 n_is, and f32
-                # represents integers exactly up to there -- so guard the
-                # bound loudly instead of letting the accounting drift
-                # silently at larger scales.
-                accs, ul, dl, oh = (np.asarray(o) for o in outs)
-                if max((float(np.max(np.abs(v))) if v.size else 0.0)
-                       for v in (ul, dl, oh)) >= 2.0 ** 24:
-                    raise OverflowError(
-                        "per-round traced bits exceed the f32 integer-exact "
-                        "range (2**24); run mode='host' for exact accounting "
-                        "at this scale")
-                ul64 = np.asarray(ul, np.float64)
-                dl64 = np.asarray(dl, np.float64)
-                oh64 = np.asarray(oh, np.float64)
-                if faulted:
-                    rows = [_faulted_round_bits(
-                        float(ul64[i]), float(dl64[i]), float(oh64[i]),
-                        views[s + i], n_active, dl_denom)
-                        for i in range(L)]
-                    snaps = meter.book_run(
-                        [r[0] for r in rows], [r[1] for r in rows],
-                        overhead_bits=[r[2] for r in rows],
-                        retransmit_bits=[r[3] for r in rows],
-                        snapshot_mask=seg_eval)
+            with jax.profiler.TraceAnnotation("fl.book"):
+                if adaptive:
+                    # Traced-bits booking: the scan's stacked per-round
+                    # bit totals are the only extra device->host transfer.
+                    # They are exact as long as they stay below 2**24 --
+                    # every term is an integer times log2 of a pow2 n_is,
+                    # and f32 represents integers exactly up to there -- so
+                    # guard the bound loudly instead of letting the
+                    # accounting drift silently at larger scales.
+                    accs, ul, dl, oh = outs
+                    if max((float(np.max(np.abs(v))) if v.size else 0.0)
+                           for v in (ul, dl, oh)) >= 2.0 ** 24:
+                        raise OverflowError(
+                            "per-round traced bits exceed the f32 "
+                            "integer-exact range (2**24); run mode='host' "
+                            "for exact accounting at this scale")
+                    ul64 = np.asarray(ul, np.float64)
+                    dl64 = np.asarray(dl, np.float64)
+                    oh64 = np.asarray(oh, np.float64)
+                    if faulted:
+                        rows = [_faulted_round_bits(
+                            float(ul64[i]), float(dl64[i]), float(oh64[i]),
+                            views[s + i], n_active, dl_denom)
+                            for i in range(L)]
+                        snaps = meter.book_run(
+                            [r[0] for r in rows], [r[1] for r in rows],
+                            overhead_bits=[r[2] for r in rows],
+                            retransmit_bits=[r[3] for r in rows],
+                            snapshot_mask=seg_eval)
+                    else:
+                        snaps = meter.book_run(ul64, dl64,
+                                               overhead_bits=oh64,
+                                               snapshot_mask=seg_eval)
                 else:
-                    snaps = meter.book_run(ul64, dl64, overhead_bits=oh64,
-                                           snapshot_mask=seg_eval)
-            else:
-                # Host-side booking with zero device involvement.
-                (accs,) = outs
-                accs = np.asarray(accs)
-                ul_base, dl_base, oh = booked["round"]
-                fl_up, fl_dn = booked.get("flush", (0.0, 0.0))
-                if faulted:
-                    uls, dls, ohs, rts = [], [], [], []
-                    for t in range(s, e):
-                        u_, d_, o_, r_ = _faulted_round_bits(
-                            ul_base, dl_base, oh, views[t], n_active,
-                            dl_denom)
-                        if flush_mask[t]:  # flush is protected: unscaled
-                            u_ += fl_up
-                            d_ += fl_dn
-                        uls.append(u_)
-                        dls.append(d_)
-                        ohs.append(o_)
-                        rts.append(r_)
-                    snaps = meter.book_run(uls, dls, overhead_bits=ohs,
-                                           retransmit_bits=rts,
-                                           snapshot_mask=seg_eval)
-                else:
-                    snaps = meter.book_run(
-                        [ul_base + (fl_up if flush_mask[t] else 0.0)
-                         for t in range(s, e)],
-                        [dl_base + (fl_dn if flush_mask[t] else 0.0)
-                         for t in range(s, e)],
-                        overhead_bits=oh, snapshot_mask=seg_eval)
-            history += [
-                {"round": int(s + i) + 1, "acc": float(accs[i]),
-                 "cum_bits": cum_bits, "bpp_so_far": bpp}
-                for i, (cum_bits, bpp) in zip(np.nonzero(seg_eval)[0], snaps)]
+                    # Host-side booking with zero device involvement.
+                    (accs,) = outs
+                    ul_base, dl_base, oh = booked["round"]
+                    fl_up, fl_dn = booked.get("flush", (0.0, 0.0))
+                    if faulted:
+                        uls, dls, ohs, rts = [], [], [], []
+                        for t in range(s, e):
+                            u_, d_, o_, r_ = _faulted_round_bits(
+                                ul_base, dl_base, oh, views[t], n_active,
+                                dl_denom)
+                            if flush_mask[t]:  # flush is protected: unscaled
+                                u_ += fl_up
+                                d_ += fl_dn
+                            uls.append(u_)
+                            dls.append(d_)
+                            ohs.append(o_)
+                            rts.append(r_)
+                        snaps = meter.book_run(uls, dls, overhead_bits=ohs,
+                                               retransmit_bits=rts,
+                                               snapshot_mask=seg_eval)
+                    else:
+                        snaps = meter.book_run(
+                            [ul_base + (fl_up if flush_mask[t] else 0.0)
+                             for t in range(s, e)],
+                            [dl_base + (fl_dn if flush_mask[t] else 0.0)
+                             for t in range(s, e)],
+                            overhead_bits=oh, snapshot_mask=seg_eval)
+                history += [
+                    {"round": int(s + i) + 1, "acc": float(accs[i]),
+                     "cum_bits": cum_bits, "bpp_so_far": bpp}
+                    for i, (cum_bits, bpp) in zip(np.nonzero(seg_eval)[0],
+                                                  snaps)]
             if checkpoint_dir and (e in bounds or e == rounds):
                 th_c, thh_c, us_c, ds_c = carry
-                self._save_state(checkpoint_dir, e, th_c, thh_c, us_c, ds_c,
-                                 meter, history, cfg_blob)
+                with jax.profiler.TraceAnnotation("fl.checkpoint"):
+                    self._save_state(checkpoint_dir, e, th_c, thh_c, us_c,
+                                     ds_c, meter, history, cfg_blob)
             s = e
         theta, theta_hat = carry[0], carry[1]
-        return self._result(history, meter, theta, theta_hat)
+        with jax.profiler.TraceAnnotation("fl.book"):
+            return self._result(history, meter, theta, theta_hat)
 
     @staticmethod
     def _result(history, meter, theta, theta_hat) -> Dict[str, Any]:

@@ -71,7 +71,9 @@ class MaskTask:
         def step(carry, inp):
             s, st = carry
             idx, mk = inp
-            g = jax.grad(loss_fn)(s, xs[idx], ys[idx], mk)
+            with jax.named_scope("local.batch"):
+                xb, yb = xs[idx], ys[idx]
+            g = jax.grad(loss_fn)(s, xb, yb, mk)
             s, st = opt.update(g, s, st)
             return (s, st), ()
 
@@ -127,7 +129,9 @@ class CFLTask:
 
         def step(carry, idx):
             w, st = carry
-            g = jax.grad(loss_fn)(w, xs[idx], ys[idx])
+            with jax.named_scope("local.batch"):
+                xb, yb = xs[idx], ys[idx]
+            g = jax.grad(loss_fn)(w, xb, yb)
             w, st = opt.update(g, w, st)
             return (w, st), ()
 
