@@ -17,9 +17,10 @@ cost is B * log2(n_is) bits per conveyed sample.
 Two codec paths are provided:
 
 * **fixed blocks** (`encode_fixed` / `decode_fixed`): all blocks have the same
-  static size.  Candidates are derived per (block, row) with
-  ``fold_in(fold_in(key, block), row)`` so the *decoder regenerates only the
-  selected row* -- decode is O(d), not O(d * n_is).  The importance-weight
+  static size.  A block's candidates are one threefry stream,
+  ``uniform(fold_in(key, block), (n_is, S))``, and the transmitted sample is
+  regenerated from the *selected row's own counters* (``_candidate_row``) on
+  both sides -- decode is O(d), not O(d * n_is).  The importance-weight
   evaluation is the matvec ``logW = X @ a + sum(b)`` (see
   ``core.bernoulli.log_ratio_coeffs``) and can be routed through the Pallas
   TPU kernel in ``repro.kernels``.
@@ -41,6 +42,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.random import threefry2x32_p
 
 from .bernoulli import clip01, log_ratio_coeffs
 
@@ -73,10 +75,57 @@ def _block_candidates(shared_key: jax.Array, block_id, n_is: int, size: int) -> 
     return jax.random.uniform(jax.random.fold_in(shared_key, block_id), (n_is, size))
 
 
-def _selected_candidate(shared_key: jax.Array, block_id, row, n_is: int, size: int) -> jax.Array:
-    """The selected uniform row for one block: (size,)."""
-    u = _block_candidates(shared_key, block_id, n_is, size)
-    return jax.lax.dynamic_index_in_dim(u, row, axis=0, keepdims=False)
+def _candidate_row(block_key: jax.Array, row, n_is: int, size: int) -> jax.Array:
+    """Row ``row`` of ``_block_candidates``' ``(n_is, size)`` tensor: (size,).
+
+    Only the row's ``size`` uniforms are computed, each from its own threefry
+    counter, bit for bit as ``jax.random.uniform(block_key, (n_is, size))``
+    draws them: with ``jax_threefry_partitionable`` on, element ``(i, s)``
+    takes the counter pair ``(0, i*size + s)``, its 32 bits are the XOR of
+    the two output words, and the top 23 become the mantissa of a float in
+    [1, 2) less 1.  Takes raw ``uint32[2]`` and typed threefry keys.
+    """
+    if not jax.config.jax_threefry_partitionable:
+        raise ValueError(
+            "_candidate_row reproduces the partitionable threefry stream; "
+            "jax_threefry_partitionable=False lays out another")
+    if jax.dtypes.issubdtype(block_key.dtype, jax.dtypes.prng_key):
+        impl = str(jax.random.key_impl(block_key))
+        data = jax.random.key_data(block_key)
+    else:
+        impl, data = jax.config.jax_default_prng_impl, block_key
+    if impl != "threefry2x32":
+        raise ValueError(f"_candidate_row needs threefry2x32 keys, got {impl}")
+    if n_is * size > 2 ** 32:
+        raise ValueError(
+            f"n_is * size = {n_is * size} candidates exceed the 2**32 counters "
+            "of one threefry word")
+    lo = (jnp.asarray(row, jnp.uint32) * jnp.uint32(size)
+          + jax.lax.iota(jnp.uint32, size))
+    # ``hi`` (all zero) is derived from ``lo`` so that both counter words
+    # carry the same batch dimensions under vmap.
+    bits1, bits2 = threefry2x32_p.bind(data[0], data[1], lo & jnp.uint32(0), lo)
+    mantissa = jax.lax.shift_right_logical(bits1 ^ bits2, jnp.uint32(9))
+    one_to_two = jax.lax.bitcast_convert_type(
+        mantissa | jnp.uint32(0x3F800000), jnp.float32)
+    return one_to_two - jnp.float32(1.0)
+
+
+def _selected_samples(shared_key: jax.Array, block_ids: jax.Array,
+                      rows: jax.Array, p_blocks: jax.Array, n_is: int) -> jax.Array:
+    """The {0,1} samples of the selected candidate rows: (nb, S).
+
+    Encoder and decoder both build the transmitted sample here, each block
+    from its one selected row and never from the block's ``n_is`` rows.
+    """
+    size = p_blocks.shape[-1]
+
+    def one(bid, row, pb):
+        u = _candidate_row(jax.random.fold_in(shared_key, bid), row, n_is, size)
+        return (u < clip01(pb)).astype(jnp.float32)
+
+    with jax.named_scope("mrc.draw"):
+        return jax.vmap(one)(block_ids, rows, p_blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -132,17 +181,19 @@ def encode_fixed(
         pc = jax.lax.dynamic_slice_in_dim(p, c * nb, nb, axis=0)  # (nb, S)
         ac = jax.lax.dynamic_slice_in_dim(a, c * nb, nb, axis=0)
         bc = jax.lax.dynamic_slice_in_dim(b, c * nb, nb, axis=0)
-        with jax.named_scope("mrc.draw"):
+        with jax.named_scope("mrc.logw"):
+            # One pass over the candidates: XLA fuses their threefry, the
+            # compare and the default weights' reduction; a ``logw_fn``
+            # kernel takes the 0/1 tensor as its input.
             u = jax.vmap(lambda bid: _block_candidates(shared_key, bid, n_is, S))(block_ids)
             x = (u < clip01(pc)[:, None, :]).astype(jnp.float32)
-        with jax.named_scope("mrc.logw"):
             logw = logw_impl(x, ac, bc)  # (nb, n_is)
         gu = jax.vmap(
             lambda bid: jax.random.uniform(jax.random.fold_in(select_key, bid), (n_is,))
         )(block_ids)
         gumbel = -jnp.log(-jnp.log(jnp.clip(gu, 1e-12, 1.0 - 1e-12)))
         idx = jnp.argmax(logw + gumbel, axis=-1).astype(jnp.int32)  # (nb,)
-        chosen = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0, :]  # (nb, S)
+        chosen = _selected_samples(shared_key, block_ids, idx, pc, n_is)  # (nb, S)
         return idx, chosen
 
     idxs, chosen = jax.lax.map(chunk_body, jnp.arange(n_chunks))
@@ -154,14 +205,7 @@ def encode_fixed(
 @functools.partial(jax.jit, static_argnames=("n_is",))
 def decode_fixed(shared_key: jax.Array, indices: jax.Array, p: jax.Array, *, n_is: int) -> jax.Array:
     """Reconstruct the encoder-selected sample from the indices: (B, S)."""
-    B, S = p.shape
-
-    def per_block(bid, idx, pb):
-        with jax.named_scope("mrc.draw"):
-            u = _selected_candidate(shared_key, bid, idx, n_is, S)
-            return (u < clip01(pb)).astype(jnp.float32)
-
-    return jax.vmap(per_block)(jnp.arange(B), indices, p)
+    return _selected_samples(shared_key, jnp.arange(p.shape[0]), indices, p, n_is)
 
 
 def transmit_fixed(

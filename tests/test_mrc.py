@@ -76,6 +76,94 @@ class TestFixedCodec:
         np.testing.assert_array_equal(np.asarray(r1.indices), np.asarray(r2.indices))
 
 
+def _materialising_encode(shared_key, select_key, q, p, *, n_is, chunk):
+    """The fixed-block encode written out with the full candidate tensor:
+    every chunk's (nb, n_is, S) f32 0/1 ``x``, the sample gathered from it."""
+    B, S = q.shape
+    nb = min(chunk, B)
+    n_chunks = -(-B // nb)
+    half = jnp.full((n_chunks * nb - B, S), 0.5, q.dtype)
+    q, p = jnp.concatenate([q, half]), jnp.concatenate([p, half])
+    a, b = log_ratio_coeffs(q, p)
+    idxs, samples = [], []
+    for c in range(n_chunks):
+        ids = c * nb + jnp.arange(nb)
+        u = jax.vmap(lambda i: jax.random.uniform(
+            jax.random.fold_in(shared_key, i), (n_is, S)))(ids)
+        x = (u < clip01(p[ids])[:, None, :]).astype(jnp.float32)
+        logw = jnp.einsum("bis,bs->bi", x, a[ids]) + jnp.sum(b[ids], -1, keepdims=True)
+        gu = jax.vmap(lambda i: jax.random.uniform(
+            jax.random.fold_in(select_key, i), (n_is,)))(ids)
+        gumbel = -jnp.log(-jnp.log(jnp.clip(gu, 1e-12, 1.0 - 1e-12)))
+        idx = jnp.argmax(logw + gumbel, axis=-1).astype(jnp.int32)
+        idxs.append(idx)
+        samples.append(jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0, :])
+    return jnp.concatenate(idxs)[:B], jnp.concatenate(samples)[:B]
+
+
+class TestCandidateRow:
+    """The selected row is regenerated from its own threefry counters, bit
+    for bit the row of the block's full candidate tensor."""
+
+    @pytest.mark.parametrize("typed", [False, True], ids=["raw", "typed"])
+    @pytest.mark.parametrize("n_is,size,block,row", [
+        (256, 256, 0, 0),
+        (256, 256, 776, 255),
+        (256, 256, 3, 128),
+        (16, 200, 5, 15),      # n_is != S, S not a multiple of 128
+        (64, 33, 1, 7),
+        (5, 300, 2, 0),
+    ])
+    def test_matches_full_tensor_row(self, typed, n_is, size, block, row):
+        key = jax.random.key(11) if typed else jax.random.PRNGKey(11)
+        block_key = jax.random.fold_in(key, block)
+        full = jax.random.uniform(block_key, (n_is, size))[row]
+        got = jax.jit(mrc._candidate_row, static_argnums=(2, 3))(
+            block_key, row, n_is, size)
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      np.asarray(full).view(np.uint32))
+
+    def test_vmapped_rows_match(self):
+        """Batched blocks and rows, as the encoder and decoder call it."""
+        key = jax.random.PRNGKey(5)
+        ids, rows = jnp.arange(6), jnp.array([0, 31, 4, 17, 31, 9])
+        got = jax.vmap(lambda b, r: mrc._candidate_row(
+            jax.random.fold_in(key, b), r, 32, 40))(ids, rows)
+        full = jax.vmap(lambda b: mrc._block_candidates(key, b, 32, 40))(ids)
+        want = jnp.take_along_axis(full, rows[:, None, None], axis=1)[:, 0]
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_refuses_non_partitionable_threefry(self):
+        prev = jax.config.jax_threefry_partitionable
+        jax.config.update("jax_threefry_partitionable", False)
+        try:
+            with pytest.raises(ValueError, match="partitionable"):
+                mrc._candidate_row(KEY, 0, 8, 16)
+        finally:
+            jax.config.update("jax_threefry_partitionable", prev)
+
+    def test_refuses_other_prng_impl(self):
+        with pytest.raises(ValueError, match="threefry2x32"):
+            mrc._candidate_row(jax.random.key(0, impl="rbg"), 0, 8, 16)
+
+    def test_refuses_more_candidates_than_counters(self):
+        with pytest.raises(ValueError, match="2\\*\\*32"):
+            mrc._candidate_row(KEY, 0, 2 ** 24, 512)
+
+
+class TestMaterialisingOracle:
+    @pytest.mark.parametrize("chunk", [4, 16])
+    def test_encode_matches_materialising_encode(self, chunk):
+        """Indices and sample equal the encode that gathers from the full
+        (nb, n_is, S) tensor, with a padded tail at both chunk sizes."""
+        q, p = _qp(jax.random.fold_in(KEY, 21), b=21, s=40)
+        sk, sel = jax.random.fold_in(KEY, 4), jax.random.fold_in(KEY, 5)
+        res = mrc.encode_fixed(sk, sel, q, p, n_is=32, chunk=chunk)
+        idx, sample = _materialising_encode(sk, sel, q, p, n_is=32, chunk=chunk)
+        np.testing.assert_array_equal(np.asarray(res.indices), np.asarray(idx))
+        np.testing.assert_array_equal(np.asarray(res.sample), np.asarray(sample))
+
+
 class TestSegmentCodec:
     def test_roundtrip(self):
         d, n_seg = 64, 4

@@ -14,12 +14,14 @@ compilation cache is off around these compiles: an entry written for a
 described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import mrc
 from repro.core.blocks import AdaptiveAllocation
 from repro.kernels import ops
 
@@ -82,3 +84,49 @@ def test_segment_logw_compiles_at_largest_bucket(one_chip, d):
             u, p, a, b, seg, n_seg=n_seg, interpret=False),
         _shape(one_chip, (N_IS, d)), vec, vec, vec,
         _shape(one_chip, (d,), jnp.int32))
+
+
+# The PR cell's fixed-block encode: 10 clients, d = 198,800 in 777 blocks
+# of 256, n_is 256, the registry's chunk of 16.
+ENC_CLIENTS, ENC_BLOCKS, ENC_SIZE, ENC_NIS = 10, 777, 256, 256
+
+
+def _loop_body_outputs(hlo: str):
+    """(computation, output type) of every top-level instruction of each
+    while body in optimised HLO text; fused computations are not bodies."""
+    bodies = set(re.findall(r"body=%([\w.\-]+)", hlo))
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+        elif line == "}":
+            comp = None
+        elif comp in bodies and " = " in line:
+            rhs = line.split(" = ", 1)[1]
+            if rhs.startswith("("):  # a tuple type: up to its closing paren
+                depth = 0
+                for i, ch in enumerate(rhs):
+                    depth += (ch == "(") - (ch == ")")
+                    if depth == 0:
+                        break
+                yield comp, rhs[:i + 1]
+            else:
+                yield comp, rhs.split(" ", 1)[0]
+
+
+def test_fixed_encode_writes_no_candidate_tensor(one_chip):
+    """No top-level op of the chunk loop outputs a (clients, chunk, n_is, S)
+    buffer: the candidates are generated once, inside the scoring fusion,
+    and the sample is regenerated from the selected rows alone."""
+    def enc(sk, sel, q, p):
+        return jax.vmap(lambda k, s, q, p: mrc.encode_fixed(
+            k, s, q, p, n_is=ENC_NIS, chunk=CHUNK))(sk, sel, q, p)
+
+    keys = _shape(one_chip, (ENC_CLIENTS, 2), jnp.uint32)
+    blocks = _shape(one_chip, (ENC_CLIENTS, ENC_BLOCKS, ENC_SIZE))
+    hlo = jax.jit(enc).lower(keys, keys, blocks, blocks).compile().as_text()
+    outputs = list(_loop_body_outputs(hlo))
+    assert outputs, "the chunk loop's body was not found"
+    full = f"[{ENC_CLIENTS},{CHUNK},{ENC_NIS},{ENC_SIZE}]"
+    assert not [o for o in outputs if full in o[1]]
