@@ -1039,8 +1039,10 @@ class FLEngine:
         """Build (jitted runner, trace-time booked-bits record) for one
         run signature.  Everything round-varying (seed key, cohort
         schedule, eval/flush masks, fault masks, carry, model/dataset
-        arrays) is a runner *argument*; the spec, plans and shapes are
-        baked into the trace.  With ``faulted`` the scan consumes the
+        arrays) and the task itself (a pytree: its fixed weights and test
+        set are the leaves) are runner *arguments*, so no array is
+        compiled in as a constant; the spec, plans and shapes are baked
+        into the trace.  With ``faulted`` the scan consumes the
         precomputed fault tables as extra per-round xs (weights, keep
         masks, the all-fail flag) -- the identical tables the host loop
         reads, so both modes produce the same faulted trajectory.
@@ -1067,7 +1069,7 @@ class FLEngine:
         # as traced f32 per-round vectors instead.
         booked: Dict[str, Any] = {}
 
-        def fl_rounds(base, carry0, sx, sy, xs_all):
+        def fl_rounds(base, carry0, sx, sy, xs_all, task):
             self.fused_trace_count += 1  # Python side effect: trace-time only
 
             def body(carry, xs):
@@ -1257,7 +1259,8 @@ class FLEngine:
                 fn, booked = prog
                 xs = {k: v[s:e] for k, v in xs_full.items()}
             with jax.profiler.TraceAnnotation("fl.dispatch"):
-                carry, outs = fn(base, carry, shards.x, shards.y, xs)
+                carry, outs = fn(base, carry, shards.x, shards.y, xs,
+                                 self.task)
             # The first host read of the outputs waits for the device.
             with jax.profiler.TraceAnnotation("fl.fetch"):
                 outs = [np.asarray(o) for o in outs]

@@ -1,8 +1,12 @@
-"""Small pure-JAX classifier networks for the FL experiments.
+"""Pure-JAX classifier networks for the FL experiments.
 
-Bias-free CNN/MLP families mirroring the paper's LeNet5 / 4CNN / 6CNN
-(scaled to the synthetic datasets).  For probabilistic-mask training the
-weights use the *signed-constant* initialization of Ramanujan et al. (2020):
+Bias-free families: CNNs mirroring the paper's LeNet5 / 4CNN / 6CNN
+(scaled to the synthetic datasets), MLPs, and the Vision Transformer of
+Dosovitskiy et al. (2020) (``make_vit``: pre-LayerNorm blocks without
+affine parameters).  Every builder returns a :class:`Net` whose weights are
+a flat list of arrays, so ``ravel_pytree`` gives the flat parameter vector
+the FL channels carry.  For probabilistic-mask training the weights use the
+*signed-constant* initialization of Ramanujan et al. (2020):
 w = sign(n) * std_kaiming -- the setting in which random subnetworks are
 known to be expressive.
 """
@@ -34,12 +38,15 @@ def _maxpool(x):
     )
 
 
-def _kaiming_signed(key, shape, fan_in, signed_constant: bool):
-    std = math.sqrt(2.0 / fan_in)
+def _normal(key, shape, std: float, signed_constant: bool):
     w = jax.random.normal(key, shape)
     if signed_constant:
         return jnp.sign(w) * std
     return w * std
+
+
+def _kaiming_signed(key, shape, fan_in, signed_constant: bool):
+    return _normal(key, shape, math.sqrt(2.0 / fan_in), signed_constant)
 
 
 def make_cnn(
@@ -103,6 +110,83 @@ def make_mlp(
         for w_ in weights[:-1]:
             h = jax.nn.relu(h @ w_)
         return h @ weights[-1]
+
+    return Net(init=init, apply=apply)
+
+
+VIT_EMBED_STD = 0.02  # class token and position embeddings
+
+
+def _layer_norm(x, eps: float = 1e-6):
+    """LayerNorm over the last axis, without scale or shift."""
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps)
+
+
+def make_vit(
+    hw: int = 224, channels: int = 3, patch: int = 16, width: int = 768,
+    depth: int = 12, heads: int = 12, mlp_width: int = 3072,
+    n_classes: int = 10, signed_constant: bool = False,
+) -> Net:
+    """Vision Transformer (Dosovitskiy et al. 2020), bias-free.
+
+    Weights, in order: the patch embedding (patch*patch*channels, width),
+    the class token (width,), the position embeddings (tokens, width), then
+    per block qkv (width, 3*width), out (width, width), fc1 (width,
+    mlp_width), fc2 (mlp_width, width), and the head (width, n_classes).
+    Pre-LayerNorm blocks (no affine parameters, eps 1e-6): softmax
+    self-attention over all tokens and an exact-GELU MLP, each on a
+    residual; a final LayerNorm and the linear head on the class token.
+    Matrices take Kaiming init, the embeddings ``VIT_EMBED_STD``.
+    """
+    if hw % patch or width % heads:
+        raise ValueError(f"hw={hw} not a multiple of patch={patch}, or "
+                         f"width={width} not a multiple of heads={heads}")
+    grid = hw // patch
+    tokens = grid * grid + 1
+    pdim = patch * patch * channels
+    hd = width // heads
+    shapes: List[Tuple[Tuple[int, ...], float]] = [  # (shape, init std)
+        ((pdim, width), math.sqrt(2.0 / pdim)),
+        ((width,), VIT_EMBED_STD),
+        ((tokens, width), VIT_EMBED_STD)]
+    for _ in range(depth):
+        shapes += [((width, 3 * width), math.sqrt(2.0 / width)),
+                   ((width, width), math.sqrt(2.0 / width)),
+                   ((width, mlp_width), math.sqrt(2.0 / width)),
+                   ((mlp_width, width), math.sqrt(2.0 / mlp_width))]
+    shapes.append(((width, n_classes), math.sqrt(2.0 / width)))
+
+    def init(key):
+        keys = jax.random.split(key, len(shapes))
+        return [_normal(k, s, std, signed_constant)
+                for k, (s, std) in zip(keys, shapes)]
+
+    def attention(h, w_qkv, w_out):
+        b = h.shape[0]
+        qkv = (_layer_norm(h) @ w_qkv).reshape(b, tokens, 3, heads, hd)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (1.0 / math.sqrt(hd))
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+        return o.reshape(b, tokens, width) @ w_out
+
+    def apply(weights, x):
+        b = x.shape[0]
+        with jax.named_scope("vit.patch"):
+            p = x.reshape(b, grid, patch, grid, patch, channels)
+            p = p.transpose(0, 1, 3, 2, 4, 5).reshape(b, grid * grid, pdim)
+            cls = jnp.broadcast_to(weights[1], (b, 1, width))
+            h = jnp.concatenate([cls, p @ weights[0]], axis=1) + weights[2]
+        for i in range(depth):
+            w_qkv, w_out, w_fc1, w_fc2 = weights[3 + 4 * i: 7 + 4 * i]
+            with jax.named_scope("vit.attn"):
+                h = h + attention(h, w_qkv, w_out)
+            with jax.named_scope("vit.mlp"):
+                y = jax.nn.gelu(_layer_norm(h) @ w_fc1, approximate=False)
+                h = h + y @ w_fc2
+        with jax.named_scope("vit.head"):
+            return _layer_norm(h[:, 0]) @ weights[-1]
 
     return Net(init=init, apply=apply)
 

@@ -11,6 +11,13 @@
 * ``CFLTask``: conventional FL.  Local training runs L epochs of Adam/SGD
   from the client's model estimate and returns the model *delta* (the
   "gradient" that the compressors quantize).
+
+Both tasks are pytrees: their frozen arrays (the fixed weights, the test
+set) are leaves, the net and the hyperparameters static.  Every jitted
+method takes ``self`` as an argument, so a program that calls the task --
+the engine's fused round scan, the host loop's jits -- receives the arrays
+as arguments and compiles none of them in as a constant.  A new seed (new
+weights, new data) then reuses the compiled program.
 """
 from __future__ import annotations
 
@@ -26,7 +33,11 @@ from repro.core.bernoulli import clip01, inv_sigmoid
 from .nets import Net, accuracy, cross_entropy, flatten_weights
 
 
-@dataclass(eq=False)  # hashable by identity: methods are jitted with static self
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["w0_flat", "x_test", "y_test"],
+                   meta_fields=["net", "unravel", "local_epochs", "batch_size",
+                                "lr", "optimizer", "theta_init"])
+@dataclass(eq=False)
 class MaskTask:
     net: Net
     w0_flat: jax.Array          # fixed signed-constant weights, flattened
@@ -49,8 +60,19 @@ class MaskTask:
         return jnp.full((self.d,), self.theta_init, jnp.float32)
 
     # -- client step ------------------------------------------------------
-    @functools.partial(jax.jit, static_argnums=0)
-    def local_train(self, theta: jax.Array, xs: jax.Array, ys: jax.Array, key: jax.Array):
+    def ste_loss(self, s: jax.Array, xb: jax.Array, yb: jax.Array,
+                 mk: jax.Array) -> jax.Array:
+        """Batch cross-entropy under a mask drawn from sigmoid(s); its
+        gradient in s is the straight-through estimator's."""
+        prob = jax.nn.sigmoid(s)
+        m = jax.random.bernoulli(mk, prob).astype(jnp.float32)
+        m_ste = m + prob - jax.lax.stop_gradient(prob)  # straight-through
+        weights = self.unravel(self.w0_flat * m_ste)
+        return cross_entropy(self.net.apply(weights, xb), yb)
+
+    @jax.jit
+    def local_train(self, theta: jax.Array, xs: jax.Array, ys: jax.Array,
+                    key: jax.Array):
         """L epochs of score-space SGD with STE; returns the posterior q."""
         shard = xs.shape[0]
         bs = min(self.batch_size, shard)
@@ -58,14 +80,6 @@ class MaskTask:
         n_steps = self.local_epochs * steps_per_epoch
         kb, km = jax.random.split(key)
         batch_idx = jax.random.randint(kb, (n_steps, bs), 0, shard)
-
-        def loss_fn(s, xb, yb, mk):
-            prob = jax.nn.sigmoid(s)
-            m = jax.random.bernoulli(mk, prob).astype(jnp.float32)
-            m_ste = m + prob - jax.lax.stop_gradient(prob)  # straight-through
-            weights = self.unravel(self.w0_flat * m_ste)
-            return cross_entropy(self.net.apply(weights, xb), yb)
-
         opt = optim.adam(self.lr) if self.optimizer == "adam" else optim.sgd(self.lr)
 
         def step(carry, inp):
@@ -73,7 +87,7 @@ class MaskTask:
             idx, mk = inp
             with jax.named_scope("local.batch"):
                 xb, yb = xs[idx], ys[idx]
-            g = jax.grad(loss_fn)(s, xb, yb, mk)
+            g = jax.grad(self.ste_loss)(s, xb, yb, mk)
             s, st = opt.update(g, s, st)
             return (s, st), ()
 
@@ -83,14 +97,10 @@ class MaskTask:
         return clip01(jax.nn.sigmoid(s_fin))
 
     # -- evaluation -------------------------------------------------------
-    def evaluate(self, theta: jax.Array) -> float:
+    @jax.jit
+    def evaluate(self, theta: jax.Array) -> jax.Array:
         """Accuracy with the expected mask (w * theta) -- low-variance eval."""
         weights = self.unravel(self.w0_flat * theta)
-        return accuracy(self.net.apply, weights, self.x_test, self.y_test)
-
-    def evaluate_sampled(self, theta: jax.Array, key: jax.Array) -> float:
-        m = jax.random.bernoulli(key, clip01(theta)).astype(jnp.float32)
-        weights = self.unravel(self.w0_flat * m)
         return accuracy(self.net.apply, weights, self.x_test, self.y_test)
 
 
@@ -101,6 +111,10 @@ def make_mask_task(net: Net, key: jax.Array, x_test, y_test, **kw) -> MaskTask:
                     x_test=x_test, y_test=y_test, **kw)
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["x_test", "y_test"],
+                   meta_fields=["net", "unravel", "d", "local_epochs",
+                                "batch_size", "local_lr", "optimizer"])
 @dataclass(eq=False)
 class CFLTask:
     net: Net
@@ -113,8 +127,9 @@ class CFLTask:
     local_lr: float = 3e-4
     optimizer: str = "adam"
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def local_train(self, theta: jax.Array, xs: jax.Array, ys: jax.Array, key: jax.Array):
+    @jax.jit
+    def local_train(self, theta: jax.Array, xs: jax.Array, ys: jax.Array,
+                    key: jax.Array):
         """Return the local model delta ("gradient") after L epochs."""
         shard = xs.shape[0]
         bs = min(self.batch_size, shard)
@@ -138,8 +153,10 @@ class CFLTask:
         (w_fin, _), _ = jax.lax.scan(step, (theta, opt.init(theta)), batch_idx)
         return theta - w_fin  # "gradient" = negative update direction
 
-    def evaluate(self, theta: jax.Array) -> float:
-        return accuracy(self.net.apply, self.unravel(theta), self.x_test, self.y_test)
+    @jax.jit
+    def evaluate(self, theta: jax.Array) -> jax.Array:
+        return accuracy(self.net.apply, self.unravel(theta), self.x_test,
+                        self.y_test)
 
 
 def make_cfl_task(net: Net, key: jax.Array, x_test, y_test, **kw) -> Tuple[CFLTask, jax.Array]:

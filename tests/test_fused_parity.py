@@ -19,6 +19,7 @@ its pow2 plan space, and arranged via ``buckets=`` for the segment codec --
 parity is again exact.
 """
 import math
+import re
 
 import jax
 import numpy as np
@@ -29,10 +30,17 @@ from repro.core.blocks import (AdaptiveAllocation, AdaptiveAvgAllocation,
 from repro.fl import registry
 from repro.fl.data import make_synthetic, partition_iid
 from repro.fl.engine import FLEngine
-from repro.fl.nets import make_mlp
+from repro.fl.nets import make_mlp, make_vit
 from repro.fl.tasks import make_cfl_task, make_mask_task
 
 SCHEMES = registry.all_schemes(n=3, d=1472, n_is=16, block=64, reset_period=2)
+# A small ViT (hw 16, patch 4, width 32, 4 heads, mlp 64, depth 2) under GR.
+VIT_ARGS = dict(hw=16, channels=3, patch=4, width=32, depth=2, heads=4,
+                mlp_width=64, n_classes=10)
+VIT_GR = ("vit-bicompfl-gr", "vit",
+          lambda: registry.bicompfl_spec("GR", allocation=FixedAllocation(64),
+                                         n_is=16))
+SETUPS = {"mask": "mask_setup", "vit": "vit_setup"}
 
 
 @pytest.fixture(scope="module")
@@ -43,6 +51,18 @@ def mask_setup():
     net = make_mlp(in_dim=36, widths=(32,), signed_constant=True)
     task = make_mask_task(net, jax.random.fold_in(k, 2), test.x, test.y,
                           local_epochs=1, batch_size=40)
+    return task, shards
+
+
+@pytest.fixture(scope="module")
+def vit_setup():
+    k = jax.random.PRNGKey(5)
+    train, test = make_synthetic(k, n_train=96, n_test=40, hw=16, channels=3,
+                                 noise=0.5)
+    shards = partition_iid(jax.random.fold_in(k, 1), train, 3, 32)
+    net = make_vit(**VIT_ARGS, signed_constant=True)
+    task = make_mask_task(net, jax.random.fold_in(k, 2), test.x, test.y,
+                          local_epochs=1, batch_size=16)
     return task, shards
 
 
@@ -85,14 +105,14 @@ def _run_both(task, spec_factory, shards, theta0=None, *, rounds=3, seed=11,
     return host
 
 
-@pytest.mark.parametrize("name,kind,factory", SCHEMES,
-                         ids=[s[0] for s in SCHEMES])
-def test_fused_matches_host(mask_setup, cfl_setup, name, kind, factory):
-    if kind == "mask":
-        task, shards = mask_setup
+@pytest.mark.parametrize("name,kind,factory", SCHEMES + [VIT_GR],
+                         ids=[s[0] for s in SCHEMES + [VIT_GR]])
+def test_fused_matches_host(request, name, kind, factory):
+    if kind in SETUPS:
+        task, shards = request.getfixturevalue(SETUPS[kind])
         _run_both(task, factory, shards)
     else:
-        task, theta0, shards = cfl_setup
+        task, theta0, shards = request.getfixturevalue("cfl_setup")
         # reset_period=2 inside 3 rounds exercises the lax.cond flush branch
         _run_both(task, factory, shards, theta0)
 
@@ -276,3 +296,81 @@ def test_fixed_allocation_auto_uses_fused(mask_setup):
     engine = FLEngine(task, registry.bicompfl_spec(
         "GR", allocation=FixedAllocation(64), n_is=16, n_dl=3))
     assert engine.fused_supported()
+
+
+# -- no task array is compiled into the fused program -------------------------
+
+class _Lowered(Exception):
+    """Carries the fused program's StableHLO out of ``FLEngine.run``."""
+
+
+def _largest_constant_bytes(text: str) -> int:
+    """Bytes of the largest non-splat constant literal in StableHLO text."""
+    best = 0
+    for payload, ty in re.findall(
+            r"stablehlo\.constant dense<(.)[^>]*> : tensor<([^>]*)>", text):
+        if payload not in ('"', "["):  # a splat: one value, broadcast
+            continue
+        *dims, dtype = ty.split("x")
+        bits = int(re.search(r"(\d+)$", dtype).group(1))
+        best = max(best, math.prod(int(x) for x in dims) * max(bits // 8, 1))
+    return best
+
+
+def _fused_program_text(task, spec, shards, theta0=None) -> str:
+    engine = FLEngine(task, spec)
+    build = engine._build_fused
+
+    def lower_only(**kw):
+        fn, booked = build(**kw)
+
+        def runner(*args):
+            raise _Lowered(fn.lower(*args).as_text())
+        return runner, booked
+
+    engine._build_fused = lower_only
+    with pytest.raises(_Lowered) as caught:
+        engine.run(shards, theta0, rounds=2, seed=0, mode="fused")
+    return str(caught.value)
+
+
+MIB = 2 ** 20
+
+
+@pytest.mark.parametrize("case", ["mlp-gr", "mlp-fedavg", "vit-gr"])
+def test_fused_program_holds_no_large_constant(case):
+    """The fixed weights and the test set reach ``fl_rounds`` as arguments:
+    each exceeds 1 MiB here, and no constant of the lowered program does."""
+    k = jax.random.PRNGKey(9)
+    if case.startswith("vit"):
+        hw, channels, n_test = 16, 3, 1200
+        net = make_vit(**VIT_ARGS, signed_constant=True)
+    else:
+        hw, channels, n_test = 28, 1, 512
+        net = make_mlp(in_dim=784, widths=(400,), signed_constant=True)
+    train, test = make_synthetic(k, n_train=64, n_test=n_test, hw=hw,
+                                 channels=channels)
+    shards = partition_iid(jax.random.fold_in(k, 1), train, 3, 16)
+    assert test.x.nbytes > MIB
+    if case == "mlp-fedavg":
+        task, theta0 = make_cfl_task(net, jax.random.fold_in(k, 2), test.x,
+                                     test.y, local_epochs=1, batch_size=16)
+        spec = registry.baseline_spec("fedavg", n=3, d=int(theta0.shape[0]))
+    else:
+        task, theta0 = make_mask_task(net, jax.random.fold_in(k, 2), test.x,
+                                      test.y, local_epochs=1,
+                                      batch_size=16), None
+        spec = registry.bicompfl_spec("GR", allocation=FixedAllocation(64),
+                                      n_is=16)
+        if case == "mlp-gr":
+            assert task.w0_flat.nbytes > MIB
+    text = _fused_program_text(task, spec, shards, theta0)
+    assert "fl_rounds" in text
+    assert _largest_constant_bytes(text) <= MIB
+
+
+def test_largest_constant_bytes_reads_literals():
+    text = ('%0 = stablehlo.constant dense<"0x0000"> : tensor<512x1024xf32>\n'
+            '%1 = stablehlo.constant dense<1.0> : tensor<4096x4096xf32>\n'
+            '%2 = stablehlo.constant dense<[1, 2]> : tensor<2xi32>\n')
+    assert _largest_constant_bytes(text) == 512 * 1024 * 4
