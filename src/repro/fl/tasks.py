@@ -33,6 +33,15 @@ from repro.core.bernoulli import clip01, inv_sigmoid
 from .nets import Net, accuracy, cross_entropy, flatten_weights
 
 
+def _fetch_form(net: Net, xs: jax.Array) -> jax.Array:
+    """The client's shard in the form the net's first layer reads, made
+    once before the step loop: rows for a net that flattens each sample,
+    so that every step gathers whole rows.  On the TPU a shard of narrow
+    images (28x28) is laid out sample-minor, and gathering an image from
+    it pulls one lane of every tile.  Nets that read images keep them."""
+    return xs.reshape(xs.shape[0], -1) if net.reads_rows else xs
+
+
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=["w0_flat", "x_test", "y_test"],
                    meta_fields=["net", "unravel", "local_epochs", "batch_size",
@@ -81,6 +90,7 @@ class MaskTask:
         kb, km = jax.random.split(key)
         batch_idx = jax.random.randint(kb, (n_steps, bs), 0, shard)
         opt = optim.adam(self.lr) if self.optimizer == "adam" else optim.sgd(self.lr)
+        xs = _fetch_form(self.net, xs)
 
         def step(carry, inp):
             s, st = carry
@@ -138,6 +148,7 @@ class CFLTask:
         batch_idx = jax.random.randint(key, (n_steps, bs), 0, shard)
 
         opt = optim.adam(self.local_lr) if self.optimizer == "adam" else optim.sgd(self.local_lr)
+        xs = _fetch_form(self.net, xs)
 
         def loss_fn(w, xb, yb):
             return cross_entropy(self.net.apply(self.unravel(w), xb), yb)

@@ -130,3 +130,54 @@ def test_fixed_encode_writes_no_candidate_tensor(one_chip):
     assert outputs, "the chunk loop's body was not found"
     full = f"[{ENC_CLIENTS},{CHUNK},{ENC_NIS},{ENC_SIZE}]"
     assert not [o for o in outputs if full in o[1]]
+
+
+# The 2NN's local training, vmapped over the cells' 10 clients.  1,280
+# samples a client is about the smallest shard at which the image fetch
+# still made XLA carry a sample-minor copy of the shards into the step loop
+# (1,248 did not); the cells hold 6,000.
+FETCH_CLIENTS, FETCH_SHARD, FETCH_HW = 10, 1280, 28
+
+
+def _shard_layouts(hlo: str):
+    """Minor-to-major layouts of the (clients, shard, ...) buffers that a
+    while loop carries: the shard copies the step loop reads."""
+    carried = " ".join(line.split(" = ", 1)[1].split(" while(")[0]
+                       for line in hlo.splitlines() if " while(" in line)
+    dims = rf"\w+\[{FETCH_CLIENTS},{FETCH_SHARD},[\d,]+\]"
+    return [tuple(int(i) for i in m.split(","))
+            for m in re.findall(dims + r"\{([\d,]+)", carried)]
+
+
+@pytest.mark.parametrize("kind", ["mask", "dense"])
+def test_mlp_fetch_gathers_rows(one_chip, kind):
+    """Each step of a ``make_mlp`` net's local training gathers whole rows
+    of 784 features, from a copy of the shards laid out sample-major; a
+    sample-minor copy made each image a gather of one lane per tile."""
+    from repro.fl.nets import make_mlp
+    from repro.fl.tasks import make_cfl_task, make_mask_task
+
+    in_dim = FETCH_HW * FETCH_HW
+    net = make_mlp(in_dim=in_dim, widths=(200, 200),
+                   signed_constant=kind == "mask")
+    key = jax.random.PRNGKey(0)
+    x_test = jnp.zeros((8, FETCH_HW, FETCH_HW, 1))
+    y_test = jnp.zeros((8,), jnp.int32)
+    if kind == "mask":
+        task = make_mask_task(net, key, x_test, y_test)
+    else:
+        task, _ = make_cfl_task(net, key, x_test, y_test)
+    n, s = FETCH_CLIENTS, FETCH_SHARD
+    hlo = jax.jit(lambda t, *a: jax.vmap(t.local_train)(*a)).lower(
+        task, _shape(one_chip, (n, task.d)),
+        _shape(one_chip, (n, s, FETCH_HW, FETCH_HW, 1)),
+        _shape(one_chip, (n, s), jnp.int32),
+        _shape(one_chip, (n, 2), jnp.uint32)).compile().as_text()
+    fetch = [line for line in hlo.splitlines()
+             if " gather(" in line and "local.batch" in line]
+    slices = {re.search(r"slice_sizes=\{([\d,]+)\}", line).group(1)
+              for line in fetch}
+    assert slices == {"1,1", f"1,1,{in_dim}"}  # labels, rows
+    layouts = _shard_layouts(hlo)
+    assert layouts, "no shard copy is carried by the step loop"
+    assert all(lay[0] != 1 for lay in layouts), layouts
