@@ -12,9 +12,9 @@ import time
 
 import jax
 
-from repro.fl.baselines import BaselineConfig, run_baseline
+from repro.fl import registry
 from repro.fl.data import make_synthetic, partition_iid
-from repro.fl.federator import CFLConfig, run_bicompfl_cfl
+from repro.fl.engine import FLEngine
 from repro.fl.nets import make_mlp
 from repro.fl.tasks import make_cfl_task
 from repro import compile_cache
@@ -32,16 +32,16 @@ def main():
 
     rounds = 12
     t0 = time.time()
-    out = run_bicompfl_cfl(task, theta0, shards,
-                           CFLConfig(rounds=rounds, server_lr=1.0))
+    out = FLEngine(task, registry.cfl_spec(server_lr=1.0)).run(
+        shards, theta0, rounds=rounds)
     print(f"BiCompFL-GR-CFL : acc {out['max_acc']:.3f}  "
           f"bpp {out['meter']['bpp']:.3f}  [{time.time()-t0:.0f}s]")
 
     for scheme in ("doublesqueeze", "fedavg"):
         t0 = time.time()
-        res = run_baseline(task, theta0, shards,
-                           BaselineConfig(scheme=scheme, rounds=rounds,
-                                          server_lr=1.0))
+        spec = registry.baseline_spec(scheme, n=int(shards.x.shape[0]),
+                                      d=int(theta0.shape[0]), server_lr=1.0)
+        res = FLEngine(task, spec).run(shards, theta0, rounds=rounds)
         print(f"{scheme:15s} : acc {res['max_acc']:.3f}  "
               f"bpp {res['meter']['bpp']:.3f}  [{time.time()-t0:.0f}s]")
 

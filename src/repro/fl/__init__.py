@@ -1,3 +1,2 @@
-"""Federated-learning runtime: channels, engine, tasks, data, wrappers."""
-from . import (baselines, channels, data, engine, federator, nets,  # noqa: F401
-               registry, tasks)
+"""Federated-learning runtime: channels, engine, registry, tasks, data."""
+from . import channels, data, engine, nets, registry, tasks  # noqa: F401

@@ -49,21 +49,32 @@ must always bill ``plan.billable`` (never ``plan.n_blocks``, which is only
 the static segment *capacity*) and must keep the bits expression otherwise
 shape-derived, so both representations stay exact.
 
-Object shell
-------------
-The pre-existing stateful API (``transmit`` / ``distribute`` / ``flush`` /
-``reset``) is a thin wrapper over the functional core: the shell owns the
-state pytree and threads it through the pure steps.  Instantiate a fresh
-channel per run (or ``reset()`` it) exactly as before.
+Wire hooks
+----------
+The wire audit (``FLEngine.run(wire="audit")``, cf. repro.wire) runs the
+same explicit-state protocol with each stage serialized to frames:
+
+    encode_up(ctx, state, payload, priors) -> (estimates, bits, state, msgs)
+    decode_up(ctx, msgs, priors) -> estimates
+    encode_down(ctx, state, update, theta, theta_hat, up_msgs)
+        -> (DownlinkResult, state, msgs)
+    decode_down(ctx, msgs, theta, theta_hat, env) -> DownlinkResult
+    encode_flush_up(state, n, d) -> (residual, bits, state, msgs)
+
+The encoders return exactly what ``step_up`` / ``step_down`` /
+``flush_step`` return, plus the frames; the decoders rebuild the values
+from the frames alone, and the engine lets the decoded values drive the
+round.  There is one protocol: a channel holds no state of its own, so a
+spec may be run any number of times.
 
 Key-derivation tags reproduce the seed loops exactly, so the engine is
-bit-for-bit compatible with the original ``run_bicompfl`` (see
+bit-for-bit compatible with the seed's loops (see
 tests/test_engine_parity.py).
 """
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, NamedTuple, Optional, Protocol, Tuple, runtime_checkable
 
 import jax
@@ -249,8 +260,14 @@ class UplinkChannel(Protocol):
     def step_up(self, ctx: RoundContext, state, payload: jax.Array,
                 priors: jax.Array) -> Tuple[jax.Array, float, Any]: ...
 
-    def transmit(self, ctx: RoundContext, payload: jax.Array,
-                 priors: jax.Array) -> Tuple[jax.Array, float]: ...
+    def flush_step(self, state, n: int, d: int): ...
+
+    def encode_up(self, ctx: RoundContext, state, payload: jax.Array,
+                  priors: jax.Array): ...
+
+    def decode_up(self, ctx: RoundContext, msgs, priors: jax.Array): ...
+
+    def encode_flush_up(self, state, n: int, d: int): ...
 
 
 @runtime_checkable
@@ -263,53 +280,65 @@ class DownlinkChannel(Protocol):
                   theta: jax.Array,
                   theta_hat: jax.Array) -> Tuple[DownlinkResult, Any]: ...
 
-    def distribute(self, ctx: RoundContext, update: ServerUpdate,
-                   theta: jax.Array, theta_hat: jax.Array) -> DownlinkResult: ...
+    def flush_step(self, state, n: int, d: int): ...
+
+    def encode_down(self, ctx: RoundContext, state, update: ServerUpdate,
+                    theta: jax.Array, theta_hat: jax.Array, up_msgs): ...
+
+    def decode_down(self, ctx: RoundContext, msgs, theta: jax.Array,
+                    theta_hat: jax.Array, env: WireEnv) -> DownlinkResult: ...
 
 
 # ---------------------------------------------------------------------------
-# Shell mixins: the stateful object API over the pure step functions.
+# Uplink EF sync on the wire, and the memoryless channels' trivial state.
 # ---------------------------------------------------------------------------
 
 
-class StatelessUplink:
-    """Object shell + trivial state for uplinks without memory."""
+class DenseFlushUp:
+    """``encode_flush_up`` for every uplink, over its own ``flush_step``.
+
+    Wherever ``flush_step`` bills bits, each client uploads its state row
+    as one dense f32 ``DIR_FLUSH_UP`` frame: its error-feedback row, or a
+    zero row for a channel without memory.  An uplink whose flush bills
+    nothing (the MRC channels) writes no frame.
+    """
+
+    def encode_flush_up(self, state, n: int, d: int):
+        r, bits, new_state = self.flush_step(state, n, d)
+        if not bits:
+            return r, bits, new_state, []
+        if bits != n * d * FLOAT_BITS:
+            raise NotImplementedError(
+                f"uplink flush books {bits} bits; the wire layer only knows "
+                f"the dense sync protocol ({n * d * FLOAT_BITS} bits)")
+        rows = np.asarray(state) if jax.tree.leaves(state) \
+            else np.zeros((n, d), np.float32)
+        msgs = []
+        for cid in range(n):
+            w = BitWriter()
+            wcodecs.put_dense(w, rows[cid])
+            msgs.append(_wire_msg(DIR_FLUSH_UP, cid, SERVER, w))
+        return r, bits, new_state, msgs
+
+
+def decode_flush_up(msgs, n: int, d: int) -> jax.Array:
+    """The server's view of an uplink sync: the mean of the clients' rows."""
+    rows = [wcodecs.get_dense(_wire_reader(m), d) for m in msgs]
+    return jnp.mean(jnp.asarray(np.stack(rows)), axis=0)
+
+
+class StatelessUplink(DenseFlushUp):
+    """Trivial state for uplinks without memory."""
 
     def init_up_state(self, n: int, d: int):
         return EMPTY_STATE
 
-    def export_state(self):
-        """Shell-state snapshot (fault-injection carry; trivial here)."""
-        return EMPTY_STATE
-
-    def import_state(self, state) -> None:
-        pass
-
-    def transmit(self, ctx, payload, priors):
-        out, bits, _ = self.step_up(ctx, EMPTY_STATE, payload, priors)
-        return out, bits
-
-    def transmit_wire(self, ctx, payload, priors):
-        """Like ``transmit`` but also returns the encoded wire messages."""
-        out, bits, _, msgs = self.encode_up(ctx, EMPTY_STATE, payload, priors)
-        return out, bits, msgs
-
     def flush_step(self, state, n: int, d: int):
         return 0.0, 0.0, state
 
-    def flush(self, n: int, d: int):
-        return 0.0, 0.0
-
-    def flush_wire(self, n: int, d: int):
-        r, bits = self.flush(n, d)
-        return r, bits, []
-
-    def decode_flush_up(self, msgs, n: int, d: int):
-        return 0.0
-
 
 class StatelessDownlink:
-    """Object shell + trivial state for downlinks without memory."""
+    """Trivial state for downlinks without memory."""
 
     # Downlink audience: "all" (every client holds an estimate of the
     # broadcast) or "active" (client-specific payloads for the cohort
@@ -319,26 +348,8 @@ class StatelessDownlink:
     def init_down_state(self, n: int, d: int):
         return EMPTY_STATE
 
-    def export_state(self):
-        return EMPTY_STATE
-
-    def import_state(self, state) -> None:
-        pass
-
-    def distribute(self, ctx, update, theta, theta_hat):
-        res, _ = self.step_down(ctx, EMPTY_STATE, update, theta, theta_hat)
-        return res
-
-    def distribute_wire(self, ctx, update, theta, theta_hat, up_msgs):
-        res, _, msgs = self.encode_down(ctx, EMPTY_STATE, update, theta,
-                                        theta_hat, up_msgs)
-        return res, msgs
-
     def flush_step(self, state, n: int, d: int):
         return 0.0, 0.0, state
-
-    def flush(self, n: int, d: int):
-        return 0.0, 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -932,9 +943,6 @@ class DenseChannel(StatelessUplink, StatelessDownlink):
         # Stateless: a periodic sync through a dense channel only costs bits.
         return 0.0, n * d * self.bits_per_value, state
 
-    def flush(self, n, d):
-        return 0.0, n * d * self.bits_per_value
-
     # -- wire codec: raw big-endian f32 vectors ----------------------------
 
     def _check_rate(self):
@@ -979,25 +987,10 @@ class DenseChannel(StatelessUplink, StatelessDownlink):
         return DownlinkResult(th, jnp.tile(th[None], (ctx.n_clients, 1)),
                               ctx.n_clients * ctx.d * self.bits_per_value)
 
-    def flush_wire(self, n, d):
-        # Dense channels hold no EF memory: the sync uplink is the zero
-        # residual, serialized at the billed dense rate.
-        self._check_rate()
-        r, bits = self.flush(n, d)
-        msgs = []
-        for cid in range(n):
-            w = BitWriter()
-            wcodecs.put_dense(w, np.zeros(d, np.float32))
-            msgs.append(_wire_msg(DIR_FLUSH_UP, cid, SERVER, w))
-        return r, bits, msgs
-
-    def decode_flush_up(self, msgs, n, d):
-        rows = [wcodecs.get_dense(_wire_reader(m), d) for m in msgs]
-        return jnp.mean(jnp.asarray(np.stack(rows)), axis=0)
 
 
 @dataclass
-class SignEFChannel:
+class SignEFChannel(DenseFlushUp):
     """Sign compression with error feedback; ``passes>1`` repeats compression
     on the residual (Neolithic's R-pass scheme, ~``passes`` bits/param).
 
@@ -1009,12 +1002,11 @@ class SignEFChannel:
     passes: int = 1
     broadcast_shareable: bool = True
     downlink_recipients = "all"
-    _e: Optional[jax.Array] = field(default=None, repr=False)
 
-    def _compress_passes(self, v):
+    def _compress(self, v):
         """Iterated sign compression, also yielding the per-pass wire
         payload: (scale, sign-bit vector) per pass.  The reconstruction
-        ``sum_r scale_r * (+-1)`` is exactly what ``_compress`` computes
+        ``sum_r scale_r * (+-1)`` is the compressed vector
         (``sign_compress`` is scale * where(v >= 0, 1, -1))."""
         comps = []
         c = None
@@ -1028,9 +1020,26 @@ class SignEFChannel:
             comps.append((scale, sgn))
         return c, comps
 
-    def _compress(self, v):
-        c, _ = self._compress_passes(v)
-        return c
+    def _up(self, ctx, e, payload):
+        """Shared uplink core: (compressed, bits, new memory, per-pass
+        wire payload).  ``step_up`` drops the payload."""
+        if ctx.n_active != ctx.n_clients:
+            raise ValueError("error-feedback uplinks require full participation")
+        acc = payload + e
+        c, comps = jax.vmap(self._compress)(acc)
+        bits = ctx.n_clients * self.passes * (ctx.d + FLOAT_BITS)
+        return c, bits, acc - c, comps
+
+    def _down(self, ctx, e, update, theta, theta_hat):
+        """Shared downlink core: (result, new memory, per-pass payload)."""
+        g = update.delta if update.delta is not None \
+            else (theta - update.theta) / update.lr
+        agg = g + e
+        c_s, comps = self._compress(agg)
+        bits = ctx.n_clients * self.passes * (ctx.d + FLOAT_BITS)
+        return DownlinkResult(theta - update.lr * c_s,
+                              theta_hat - update.lr * c_s[None, :],
+                              bits), agg - c_s, comps
 
     # -- functional core --------------------------------------------------
     def init_up_state(self, n, d):
@@ -1040,21 +1049,12 @@ class SignEFChannel:
         return jnp.zeros((d,), jnp.float32)
 
     def step_up(self, ctx, e, payload, priors):
-        if ctx.n_active != ctx.n_clients:
-            raise ValueError("error-feedback uplinks require full participation")
-        acc = payload + e
-        c = jax.vmap(self._compress)(acc)
-        bits = ctx.n_clients * self.passes * (ctx.d + FLOAT_BITS)
-        return c, bits, acc - c
+        c, bits, e, _ = self._up(ctx, e, payload)
+        return c, bits, e
 
     def step_down(self, ctx, e, update, theta, theta_hat):
-        g = update.delta if update.delta is not None \
-            else (theta - update.theta) / update.lr
-        agg = g + e
-        c_s = self._compress(agg)
-        bits = ctx.n_clients * self.passes * (ctx.d + FLOAT_BITS)
-        return DownlinkResult(theta - update.lr * c_s,
-                              theta_hat - update.lr * c_s[None, :], bits), agg - c_s
+        res, e, _ = self._down(ctx, e, update, theta, theta_hat)
+        return res, e
 
     def flush_step(self, e, n, d):
         r = jnp.mean(e, axis=0) if e.ndim == 2 else e
@@ -1073,11 +1073,7 @@ class SignEFChannel:
         return c
 
     def encode_up(self, ctx, e, payload, priors):
-        if ctx.n_active != ctx.n_clients:
-            raise ValueError("error-feedback uplinks require full participation")
-        acc = payload + e
-        c, comps = jax.vmap(self._compress_passes)(acc)
-        bits = ctx.n_clients * self.passes * (ctx.d + FLOAT_BITS)
+        c, bits, e, comps = self._up(ctx, e, payload)
         msgs = []
         for j, cid in enumerate(np.asarray(ctx.active)):
             w = BitWriter()
@@ -1085,7 +1081,7 @@ class SignEFChannel:
                 wcodecs.put_sign_pass(w, np.asarray(scale)[j],
                                       np.asarray(sgn)[j])
             msgs.append(_wire_msg(DIR_UP, cid, SERVER, w))
-        return c, bits, acc - c, msgs
+        return c, bits, e, msgs
 
     def decode_up(self, ctx, msgs, priors):
         rows = []
@@ -1096,11 +1092,7 @@ class SignEFChannel:
         return jnp.stack(rows)
 
     def encode_down(self, ctx, e, update, theta, theta_hat, up_msgs):
-        g = update.delta if update.delta is not None \
-            else (theta - update.theta) / update.lr
-        agg = g + e
-        c_s, comps = self._compress_passes(agg)
-        bits = ctx.n_clients * self.passes * (ctx.d + FLOAT_BITS)
+        res, e, comps = self._down(ctx, e, update, theta, theta_hat)
         w = BitWriter()
         for scale, sgn in comps:
             wcodecs.put_sign_pass(w, np.asarray(scale), np.asarray(sgn))
@@ -1108,9 +1100,7 @@ class SignEFChannel:
         msgs = [Message(direction=DIR_DOWN, sender=SERVER, recipient=int(cid),
                         payload=payload, payload_bits=nbits)
                 for cid in range(ctx.n_clients)]
-        res = DownlinkResult(theta - update.lr * c_s,
-                             theta_hat - update.lr * c_s[None, :], bits)
-        return res, agg - c_s, msgs
+        return res, e, msgs
 
     def decode_down(self, ctx, msgs, theta, theta_hat, env: WireEnv):
         r = _wire_reader(msgs[0])
@@ -1121,82 +1111,28 @@ class SignEFChannel:
         return DownlinkResult(theta - lr * c_s,
                               theta_hat - lr * c_s[None, :], bits)
 
-    # -- object shell ------------------------------------------------------
-    def transmit(self, ctx, payload, priors):
-        if self._e is None:
-            self._e = jnp.zeros_like(payload)
-        out, bits, self._e = self.step_up(ctx, self._e, payload, priors)
-        return out, bits
-
-    def transmit_wire(self, ctx, payload, priors):
-        if self._e is None:
-            self._e = jnp.zeros_like(payload)
-        out, bits, self._e, msgs = self.encode_up(ctx, self._e, payload,
-                                                  priors)
-        return out, bits, msgs
-
-    def distribute(self, ctx, update, theta, theta_hat):
-        if self._e is None:
-            self._e = jnp.zeros_like(theta)
-        res, self._e = self.step_down(ctx, self._e, update, theta, theta_hat)
-        return res
-
-    def distribute_wire(self, ctx, update, theta, theta_hat, up_msgs):
-        if self._e is None:
-            self._e = jnp.zeros_like(theta)
-        res, self._e, msgs = self.encode_down(ctx, self._e, update, theta,
-                                              theta_hat, up_msgs)
-        return res, msgs
-
-    def flush(self, n, d):
-        if self._e is None:
-            return 0.0, n * d * FLOAT_BITS
-        r, bits, self._e = self.flush_step(self._e, n, d)
-        return r, bits
-
-    def flush_wire(self, n, d):
-        """Uplink EF sync: every client uploads its dense residual row."""
-        e = self._e if self._e is not None else jnp.zeros((n, d), jnp.float32)
-        rows = np.asarray(e if e.ndim == 2 else jnp.tile(e[None], (n, 1)))
-        msgs = []
-        for cid in range(n):
-            w = BitWriter()
-            wcodecs.put_dense(w, rows[cid])
-            msgs.append(_wire_msg(DIR_FLUSH_UP, cid, SERVER, w))
-        r, bits = self.flush(n, d)
-        return r, bits, msgs
-
-    def decode_flush_up(self, msgs, n, d):
-        rows = [wcodecs.get_dense(_wire_reader(m), d) for m in msgs]
-        return jnp.mean(jnp.asarray(np.stack(rows)), axis=0)
-
-    def export_state(self):
-        return self._e
-
-    def import_state(self, state) -> None:
-        self._e = state
-
-    def reset(self):
-        self._e = None
-
 
 @dataclass
-class TopKEFChannel:
+class TopKEFChannel(DenseFlushUp):
     """Top-k sparsification with error feedback (M3 uplink, k = d/n)."""
 
     k: int = 1
-    _e: Optional[jax.Array] = field(default=None, repr=False)
+
+    def _up(self, ctx, e, payload):
+        """Shared uplink core: (accumulator, compressed, bits)."""
+        if ctx.n_active != ctx.n_clients:
+            raise ValueError("error-feedback uplinks require full participation")
+        acc = payload + e
+        c = jax.vmap(lambda v: topk_compress(v, self.k))(acc)
+        return acc, c, ctx.n_clients * topk_bits(ctx.d, self.k)
 
     # -- functional core --------------------------------------------------
     def init_up_state(self, n, d):
         return jnp.zeros((n, d), jnp.float32)
 
     def step_up(self, ctx, e, payload, priors):
-        if ctx.n_active != ctx.n_clients:
-            raise ValueError("error-feedback uplinks require full participation")
-        acc = payload + e
-        c = jax.vmap(lambda v: topk_compress(v, self.k))(acc)
-        return c, ctx.n_clients * topk_bits(ctx.d, self.k), acc - c
+        acc, c, bits = self._up(ctx, e, payload)
+        return c, bits, acc - c
 
     def flush_step(self, e, n, d):
         return jnp.mean(e, axis=0), n * d * FLOAT_BITS, jnp.zeros_like(e)
@@ -1206,14 +1142,10 @@ class TopKEFChannel:
     # booked topk_bits(d, k).
 
     def encode_up(self, ctx, e, payload, priors):
-        if ctx.n_active != ctx.n_clients:
-            raise ValueError("error-feedback uplinks require full participation")
-        acc = payload + e
+        acc, c, bits = self._up(ctx, e, payload)
         kk = min(self.k, ctx.d)
         _, idxs = jax.vmap(lambda v: jax.lax.top_k(jnp.abs(v), kk))(acc)
         vals = jnp.take_along_axis(acc, idxs, axis=1)
-        c = jax.vmap(lambda v: topk_compress(v, self.k))(acc)
-        bits = ctx.n_clients * topk_bits(ctx.d, self.k)
         msgs = []
         for j, cid in enumerate(np.asarray(ctx.active)):
             w = BitWriter()
@@ -1232,50 +1164,6 @@ class TopKEFChannel:
             rows.append(jnp.zeros(ctx.d, jnp.float32)
                         .at[jnp.asarray(idx)].set(jnp.asarray(vals)))
         return jnp.stack(rows)
-
-    # -- object shell ------------------------------------------------------
-    def transmit(self, ctx, payload, priors):
-        if self._e is None:
-            self._e = jnp.zeros_like(payload)
-        out, bits, self._e = self.step_up(ctx, self._e, payload, priors)
-        return out, bits
-
-    def transmit_wire(self, ctx, payload, priors):
-        if self._e is None:
-            self._e = jnp.zeros_like(payload)
-        out, bits, self._e, msgs = self.encode_up(ctx, self._e, payload,
-                                                  priors)
-        return out, bits, msgs
-
-    def flush(self, n, d):
-        if self._e is None:
-            return 0.0, n * d * FLOAT_BITS
-        r, bits, self._e = self.flush_step(self._e, n, d)
-        return r, bits
-
-    def flush_wire(self, n, d):
-        e = self._e if self._e is not None else jnp.zeros((n, d), jnp.float32)
-        rows = np.asarray(e)
-        msgs = []
-        for cid in range(n):
-            w = BitWriter()
-            wcodecs.put_dense(w, rows[cid])
-            msgs.append(_wire_msg(DIR_FLUSH_UP, cid, SERVER, w))
-        r, bits = self.flush(n, d)
-        return r, bits, msgs
-
-    def decode_flush_up(self, msgs, n, d):
-        rows = [wcodecs.get_dense(_wire_reader(m), d) for m in msgs]
-        return jnp.mean(jnp.asarray(np.stack(rows)), axis=0)
-
-    def export_state(self):
-        return self._e
-
-    def import_state(self, state) -> None:
-        self._e = state
-
-    def reset(self):
-        self._e = None
 
 
 @dataclass

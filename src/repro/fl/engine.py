@@ -10,7 +10,8 @@ things every scheme shares and that used to be copy-pasted per loop:
 * partial participation (cohort sampling; inactive clients are *not*
   trained),
 * the block-allocation control plane,
-* periodic error-feedback synchronisation (CSER / LIEC style ``flush``),
+* periodic error-feedback synchronisation (CSER / LIEC style
+  ``flush_step``),
 * BitMeter accounting and evaluation history,
 * deterministic fault injection (:mod:`repro.fl.faults`) with degraded
   aggregation, retransmit accounting, and crash-safe resume
@@ -20,14 +21,16 @@ Two execution paths (tests/test_fused_parity.py; bit-for-bit identical
 under static block plans, accuracy/bits-parity within the bucketing bound
 under adaptive ones):
 
-* **host** -- a Python round loop.  Functional channels run through a
-  *staged* jit of the shared round core (one compiled stage per
-  (plan-shape, fault-mode) signature, cached across rounds and runs --
-  the host path stopped retracing channels every round); non-functional
-  channels and ``wire="audit"`` runs use the eager shell protocol.
-  Adaptive allocations recompute the *exact* plan from each round's KL
-  profile on the host; this path is the parity oracle for the bucketed
-  fused execution.
+* **host** -- the oracle: a Python round loop that carries
+  ``(theta, theta_hat, up_s, dn_s)`` explicitly.  Each round's stages run
+  either as a *staged* jit of the shared round core (one compiled stage
+  per (plan-shape, fault-mode) signature, cached across rounds and runs)
+  or, under ``wire="audit"``, encoded to frames and decoded outside the
+  jit (``_wire_round``).  Local training, the plan, fault masking, the
+  EF flush, booking, evaluation and checkpoints are the same code for
+  both.  Adaptive allocations recompute the *exact* plan from each
+  round's KL profile on the host; this path is the parity oracle for
+  the bucketed fused execution.
 * **fused** -- the entire multi-round run is ONE ``jax.lax.scan`` over
   rounds: channel state (error-feedback memories) is an explicit carry
   pytree threaded through the pure ``step_up`` / ``step_down`` functions,
@@ -49,10 +52,11 @@ scan as traced masks), so the same seed produces the identical faulted
 run in either mode.  Dropped / lost clients have their error-feedback
 rows and ``theta_hat`` rows *carried* (masked ``where``), surviving
 contributions are renormalised through ``RoundContext.up_weight``, an
-all-fail round keeps ``theta_hat`` (compute-then-discard select), and
-corrupted deliveries book their wasted copies into the BitMeter's
+all-fail round discards the whole computed step (compute-then-discard),
+and corrupted deliveries book their wasted copies into the BitMeter's
 ``retransmit_bits`` category -- on the wire-audit path as actual flipped
-frame copies that must fail CRC.
+frame copies that must fail CRC, with the round's bits booked from the
+frames that reached the stream.
 
 Crash-safe resume: ``checkpoint_dir=`` + ``checkpoint_every=`` write the
 full engine carry (model, per-client estimates, channel state pytrees,
@@ -105,10 +109,14 @@ from repro.core import mrc
 from repro.core.bernoulli import bern_kl, clip01
 from repro.core.bitmeter import BitMeter
 from repro.kernels.ops import bernoulli_kl_profile, bernoulli_kl_total
+from repro.wire import (DIR_CTRL, DIR_FLUSH_DOWN, DOWNLINK_DIRS, SERVER,
+                        UPLINK_DIRS, BitReader, BitWriter, Message, WireError,
+                        WireSession, scheme_wire_id)
+from repro.wire import codecs as wcodecs
 from .channels import (BlockPlan, RoundContext, ServerUpdate, TAG_COHORT,
-                       TAG_TRAIN, pin)
+                       TAG_TRAIN, WireEnv, decode_flush_up, pin)
 from .data import Dataset
-from .faults import FaultPlan, fault_report
+from .faults import FaultPlan, corrupt_copy, fault_report
 
 
 def _kl_stats(payload, priors, *, needs_profile: bool) -> Dict[str, Any]:
@@ -274,28 +282,32 @@ class FLEngine:
         self._host_jits: Dict[Any, Any] = {}
         self.host_trace_count = 0   # bumped at trace time (regression test)
 
-    # -- fused-path eligibility -------------------------------------------
+    # -- eligibility -------------------------------------------------------
 
-    def _functional_channels(self) -> bool:
-        """Both channels speak the pure-state protocol (explicit carry)."""
+    def _check_protocol(self) -> None:
+        """Refuse a spec whose channels lack the explicit-state protocol."""
         spec = self.spec
-        up_ok = all(hasattr(spec.uplink, a)
-                    for a in ("step_up", "init_up_state", "flush_step"))
-        dn_ok = all(hasattr(spec.downlink, a)
-                    for a in ("step_down", "init_down_state", "flush_step"))
-        return up_ok and dn_ok
+        missing = [f"uplink.{a}" for a in
+                   ("init_up_state", "step_up", "flush_step")
+                   if not hasattr(spec.uplink, a)]
+        missing += [f"downlink.{a}" for a in
+                    ("init_down_state", "step_down", "flush_step")
+                    if not hasattr(spec.downlink, a)]
+        if missing:
+            raise ValueError(
+                f"spec {spec.name!r} cannot run: its channels lack the "
+                f"explicit-state protocol, missing {missing}")
 
     def fused_supported(self) -> bool:
         """True when the whole run can compile to one scanned XLA program.
 
-        Only *non-functional* channels (no ``step_up`` / ``step_down``
-        protocol) force the host loop.  Adaptive allocations are fused via
-        their bucketed control plane (``bucket_plans`` / ``select_bucket``
-        / ``finalize_plan``); an allocation exposing neither a static plan
-        nor the bucket API -- or a hand-built spec combining a
-        data-dependent plan with a periodic EF flush, a pairing no
-        registry scheme produces (the flush would need the aggregator's
-        step size inside every switch branch) -- stays host-only.
+        Adaptive allocations are fused via their bucketed control plane
+        (``bucket_plans`` / ``select_bucket`` / ``finalize_plan``); an
+        allocation exposing neither a static plan nor the bucket API -- or
+        a hand-built spec combining a data-dependent plan with a periodic
+        EF flush, a pairing no registry scheme produces (the flush would
+        need the aggregator's step size inside every switch branch) --
+        stays host-only.
         """
         spec = self.spec
         if spec.allocation is not None and \
@@ -304,7 +316,7 @@ class FLEngine:
                             ("bucket_plans", "select_bucket", "finalize_plan"))
             if not bucket_ok or spec.sync_period:
                 return False
-        return self._functional_channels()
+        return True
 
     # -- cohort schedule ---------------------------------------------------
 
@@ -417,17 +429,10 @@ class FLEngine:
         if wire and (checkpoint_dir or resume_from):
             raise ValueError("wire audit cannot checkpoint or resume (the "
                              "session stream is not part of the saved carry)")
-        if (checkpoint_dir or resume_from) and not self._functional_channels():
-            raise ValueError(
-                f"spec {spec.name!r} cannot checkpoint/resume: channels "
-                "without the pure-state protocol have no explicit carry")
+        if wire:
+            self._check_wire_support()
+        self._check_protocol()
         with jax.profiler.TraceAnnotation("fl.prepare"):
-            # Stateful channels (error-feedback memories) must start fresh: a
-            # spec may be run more than once.
-            for chan in (spec.uplink, spec.downlink):
-                reset = getattr(chan, "reset", None)
-                if reset is not None:
-                    reset()
             n = int(shards.x.shape[0])
             theta = task.init_theta() if theta0 is None else theta0
             d = int(theta.shape[0])
@@ -450,21 +455,6 @@ class FLEngine:
                 views_all = fsched.run_views(schedule, dl_rec)
                 if any(v.faulty or v.all_failed for v in views_all):
                     views = views_all
-            if (views is not None and not wire
-                    and not self._functional_channels()):
-                raise ValueError(
-                    f"spec {spec.name!r} cannot run under faults without the "
-                    "pure-state channel protocol (state rows must be carried "
-                    "explicitly) or a wire session")
-            if views is not None and wire:
-                for role, chan in (("uplink", spec.uplink),
-                                   ("downlink", spec.downlink)):
-                    if not (hasattr(chan, "export_state")
-                            and hasattr(chan, "import_state")):
-                        raise ValueError(
-                            f"spec {spec.name!r} cannot run faulted wire "
-                            f"audit: {role} channel lacks "
-                            "export_state/import_state")
 
             if mode not in ("auto", "host", "fused"):
                 raise ValueError(mode)
@@ -472,9 +462,8 @@ class FLEngine:
             if mode == "fused" and not fused_ok:
                 raise ValueError(
                     f"spec {spec.name!r} needs the host control plane "
-                    "(non-functional channels, an allocation without the "
-                    "bucket API, or a data-dependent plan combined with an "
-                    "EF flush)")
+                    "(an allocation without the bucket API, or a "
+                    "data-dependent plan combined with an EF flush)")
             fused = fused_ok and mode != "host" and not wire
 
             cfg_blob = None
@@ -500,7 +489,6 @@ class FLEngine:
         else:
             session = None
             if wire:
-                from repro.wire import WireSession, scheme_wire_id
                 session = WireSession(
                     scheme_id=scheme_wire_id(spec.name or "unnamed"))
             out = self._run_host(shards, theta, theta_hat, meter,
@@ -686,11 +674,39 @@ class FLEngine:
         self._host_jits[key] = entry
         return entry
 
+    def _wire_round(self, ctx, theta, theta_hat, up_s, dn_s, payload,
+                    priors):
+        """One round's stages encoded to frames and decoded outside the jit.
+
+        The decoded values drive the round, so the run certifies that the
+        codecs are lossless.  Returns the new ``(theta, theta_hat, up_s,
+        dn_s)``, the round's ``(uplink bits, downlink bits, server lr)``,
+        and the uplink and downlink frames.
+        """
+        spec = self.spec
+        _, ul_bits, up_s, up_msgs = spec.uplink.encode_up(ctx, up_s, payload,
+                                                          priors)
+        up_out = spec.uplink.decode_up(ctx, up_msgs, priors)
+        update = spec.aggregator(ctx, theta, up_out)
+        _, dn_s, dn_msgs = spec.downlink.encode_down(ctx, dn_s, update, theta,
+                                                     theta_hat, up_msgs)
+        env = WireEnv(uplink=spec.uplink, aggregator=spec.aggregator,
+                      priors=priors, up_msgs=up_msgs, update=update)
+        theta, theta_hat, dl_bits = spec.downlink.decode_down(
+            ctx, dn_msgs, theta, theta_hat, env)
+        return ((theta, theta_hat, up_s, dn_s), (ul_bits, dl_bits, update.lr),
+                up_msgs, dn_msgs)
+
     def _run_host(self, shards, theta, theta_hat, meter, *, rounds, seed,
                   eval_every, schedule, session=None, views=None,
                   fsched=None, start_round=0, carry_in=None, history=None,
                   checkpoint_dir=None, checkpoint_every=0,
                   cfg_blob=None) -> Dict[str, Any]:
+        """The oracle loop.  Each round's stages run as the staged jit of
+        ``_round_core`` or, with a wire ``session``, as ``_wire_round``;
+        both carry ``(theta, theta_hat, up_s, dn_s)`` explicitly and share
+        the rest: local training, the plan, fault masking, the EF flush,
+        booking, evaluation and checkpoints."""
         task, spec = self.task, self.spec
         n, d = meter.n_clients, meter.d
         n_active = schedule.shape[1]
@@ -699,20 +715,11 @@ class FLEngine:
         faulted = views is not None
         dl_rec = getattr(spec.downlink, "downlink_recipients", "all")
         dl_denom = n if dl_rec == "all" else n_active
-        if session is not None:
-            self._check_wire_support()
-        # Functional channels run through the cached staged jit (explicit
-        # state carry, fault masks applied host-side between stages); the
-        # wire-audit path and non-functional channels keep the eager shell
-        # protocol.
-        staged = session is None and self._functional_channels()
-        up_s = dn_s = None
-        if staged:
-            if carry_in is not None:
-                up_s, dn_s = carry_in
-            else:
-                up_s = spec.uplink.init_up_state(n, d)
-                dn_s = spec.downlink.init_down_state(n, d)
+        if carry_in is not None:
+            up_s, dn_s = carry_in
+        else:
+            up_s = spec.uplink.init_up_state(n, d)
+            dn_s = spec.downlink.init_down_state(n, d)
 
         for t in range(start_round, rounds):
             kt = mrc.round_key(base, t)
@@ -752,7 +759,8 @@ class FLEngine:
                     msgs += [m for m in ctrl
                              if not faulted or rf.online[m.sender]]
 
-            if staged:
+            # ---- uplink -> aggregate -> downlink -------------------------
+            if session is None:
                 tj = jnp.asarray(t, jnp.int32)
                 aj = jnp.asarray(active)
                 ptok = jnp.zeros((), jnp.int32)  # pins must fire inside jit
@@ -763,39 +771,83 @@ class FLEngine:
                 th, thh, us, ds = fn(kt, tj, aj, ptok, seg, w, theta,
                                      theta_hat, up_s, dn_s, payload, priors)
                 ul_bits, dl_bits, lr = rec["ul"], rec["dl"], rec["lr"]
+            else:
+                n_wasted0 = len(session.wasted)
+                ctx = RoundContext(t=t, key=kt, n_clients=n, d=d,
+                                   active=active, plan=plan,
+                                   up_weight=jnp.asarray(rf.up_weight)
+                                   if faulted else None)
+                (th, thh, us, ds), (ul_bits, dl_bits, lr), up_msgs, \
+                    dn_msgs = self._wire_round(ctx, theta, theta_hat, up_s,
+                                               dn_s, payload, priors)
                 if faulted:
-                    # Carried, not corrupted: dropped/lost rows keep their
-                    # pre-round EF state and theta_hat estimate; an
-                    # all-fail round discards the whole computed step.
-                    us = _carry_rows(up_s, us, jnp.asarray(rf.delivered_up))
-                    thh = jnp.where(jnp.asarray(rf.delivered_dn)[:, None],
-                                    thh, theta_hat)
-                    if rf.all_failed:
-                        th, thh, us, ds = theta, theta_hat, up_s, dn_s
-                theta, theta_hat, up_s, dn_s = th, thh, us, ds
+                    # Real corrupted copies of every frame the schedule
+                    # hits; the downlink of an all-fail round never
+                    # leaves the server.
+                    up_msgs = self._wire_deliver(
+                        session, fsched, rf, t, up_msgs, owner="sender",
+                        link=0, sched=rf.senders, ok=rf.delivered_up,
+                        wasted=rf.up_wasted)
+                    dn_msgs = [] if rf.all_failed else self._wire_deliver(
+                        session, fsched, rf, t, dn_msgs, owner="recipient",
+                        link=1, sched=rf.nominal_recv & rf.online,
+                        ok=rf.delivered_dn, wasted=rf.dn_wasted)
+                msgs += up_msgs + dn_msgs
+            if faulted:
+                # Carried, not corrupted: dropped/lost rows keep their
+                # pre-round EF state and theta_hat estimate; an all-fail
+                # round discards the whole computed step.
+                us = _carry_rows(up_s, us, jnp.asarray(rf.delivered_up))
+                thh = jnp.where(jnp.asarray(rf.delivered_dn)[:, None],
+                                thh, theta_hat)
+                if rf.all_failed:
+                    th, thh, us, ds = theta, theta_hat, up_s, dn_s
+            theta, theta_hat, up_s, dn_s = th, thh, us, ds
+
+            # ---- periodic EF synchronisation (CSER / LIEC) ---------------
+            # The flush is protected signalling: exempt from faults,
+            # booked unscaled.
+            b_up = b_dn = 0.0
+            if spec.sync_period and (t + 1) % spec.sync_period == 0:
+                if session is None:
+                    r_up, b_up, up_s = spec.uplink.flush_step(up_s, n, d)
+                else:
+                    r_up, b_up, up_s, fl_msgs = spec.uplink.encode_flush_up(
+                        up_s, n, d)
+                    if fl_msgs:
+                        r_up = decode_flush_up(fl_msgs, n, d)
+                    msgs += fl_msgs
+                r_dn, b_dn, dn_s = spec.downlink.flush_step(dn_s, n, d)
+                theta = theta - lr * (r_up + r_dn)
+                if session is not None and b_dn:
+                    # The downlink flush re-broadcasts the synced model;
+                    # the decoded broadcast drives the trajectory.
+                    fd_msgs, theta = self._flush_down_msgs(theta, n, d, b_dn)
+                    msgs += fd_msgs
+                theta_hat = jnp.tile(theta[None], (n, 1))
+
+            if faulted and session is not None:
+                # Book straight from the frames that actually hit the
+                # stream (CTRL overhead rides the uplink direction), so
+                # the session reconcile is exact by construction.
+                ul_r = float(sum(m.payload_bits for m in msgs
+                                 if m.direction in UPLINK_DIRS))
+                dl_r = float(sum(m.payload_bits for m in msgs
+                                 if m.direction in DOWNLINK_DIRS))
+                rt_r = float(sum(wa.payload_bits
+                                 for wa in session.wasted[n_wasted0:]))
+                oh_r = 0.0
+            else:
                 oh_full = plan.overhead_bits * n if plan is not None else 0.0
                 if faulted:
                     ul_r, dl_r, oh_r, rt_r = _faulted_round_bits(
                         ul_bits, dl_bits, oh_full, rf, n_active, dl_denom)
                 else:
                     ul_r, dl_r, oh_r, rt_r = ul_bits, dl_bits, oh_full, 0.0
-                # ---- periodic EF synchronisation (CSER / LIEC) -----------
-                # The flush is protected signalling: exempt from faults,
-                # booked unscaled.
-                if spec.sync_period and (t + 1) % spec.sync_period == 0:
-                    r_up, b_up, up_s = spec.uplink.flush_step(up_s, n, d)
-                    r_dn, b_dn, dn_s = spec.downlink.flush_step(dn_s, n, d)
-                    theta = theta - lr * (r_up + r_dn)
-                    theta_hat = jnp.tile(theta[None], (n, 1))
-                    ul_r += b_up
-                    dl_r += b_dn
-                meter.add_round(ul_r, dl_r, overhead_bits=oh_r,
-                                retransmit_bits=rt_r)
-            else:
-                theta, theta_hat = self._shell_round(
-                    t, kt, active, plan, payload, priors, theta, theta_hat,
-                    meter, session, msgs, rf, fsched, n, d, n_active,
-                    dl_denom)
+                ul_r += b_up
+                dl_r += b_dn
+            meter.add_round(ul_r, dl_r, overhead_bits=oh_r,
+                            retransmit_bits=rt_r)
             if session is not None:
                 session.add(msgs, round=t)
 
@@ -804,7 +856,7 @@ class FLEngine:
                 history.append({"round": t + 1, "acc": float(acc),
                                 "cum_bits": meter.total_bits,
                                 "bpp_so_far": meter.total_bpp})
-            if staged and checkpoint_dir and (
+            if checkpoint_dir and (
                     (checkpoint_every and (t + 1) % checkpoint_every == 0)
                     or t + 1 == rounds):
                 with jax.profiler.TraceAnnotation("fl.checkpoint"):
@@ -812,118 +864,6 @@ class FLEngine:
                                      up_s, dn_s, meter, history, cfg_blob)
 
         return self._result(history, meter, theta, theta_hat)
-
-    def _shell_round(self, t, kt, active, plan, payload, priors, theta,
-                     theta_hat, meter, session, msgs, rf, fsched, n, d,
-                     n_active, dl_denom):
-        """One eager shell-protocol round (wire audit / non-functional).
-
-        Appends this round's frames to ``msgs`` (mutated in place) and
-        books the meter.  ``rf`` is the round's fault view or None; a
-        faulted shell round always has a wire session (enforced in
-        ``run``), injects real corrupted frame copies, and books bits
-        from the stream itself so the session reconciles exactly.
-        """
-        spec = self.spec
-        faulted = rf is not None
-        if faulted:
-            up_snap = spec.uplink.export_state()
-            dn_snap = spec.downlink.export_state()
-            n_wasted0 = len(session.wasted)
-        ctx = RoundContext(t=t, key=kt, n_clients=n, d=d, active=active,
-                           plan=plan,
-                           up_weight=jnp.asarray(rf.up_weight)
-                           if faulted else None)
-
-        # ---- uplink -> aggregate -> downlink -----------------------------
-        if session is None:
-            up_out, ul_bits = spec.uplink.transmit(ctx, payload, priors)
-        else:
-            up_out, ul_bits, up_msgs = spec.uplink.transmit_wire(
-                ctx, payload, priors)
-            up_out = spec.uplink.decode_up(ctx, up_msgs, priors)
-            if faulted:
-                spec.uplink.import_state(_carry_rows(
-                    up_snap, spec.uplink.export_state(),
-                    jnp.asarray(rf.delivered_up)))
-                msgs += self._wire_deliver(
-                    session, fsched, rf, t, up_msgs, owner="sender", link=0,
-                    sched=rf.senders, ok=rf.delivered_up,
-                    wasted=rf.up_wasted)
-            else:
-                msgs += up_msgs
-        update = spec.aggregator(ctx, theta, up_out)
-        if session is None:
-            theta, theta_hat, dl_bits = spec.downlink.distribute(
-                ctx, update, theta, theta_hat)
-        elif faulted and rf.all_failed:
-            # Compute-then-discard: the server aborts before broadcasting,
-            # every client (and the channel state) keeps its pre-round
-            # view; only the uplink traffic that did happen is billed.
-            spec.uplink.import_state(up_snap)
-            spec.downlink.import_state(dn_snap)
-            dl_bits = 0.0
-        else:
-            from .channels import WireEnv
-            _, dn_msgs = spec.downlink.distribute_wire(
-                ctx, update, theta, theta_hat, up_msgs)
-            env = WireEnv(uplink=spec.uplink, aggregator=spec.aggregator,
-                          priors=priors, up_msgs=up_msgs, update=update)
-            new_th, new_hat, dl_bits = spec.downlink.decode_down(
-                ctx, dn_msgs, theta, theta_hat, env)
-            if faulted:
-                theta = new_th
-                theta_hat = jnp.where(jnp.asarray(rf.delivered_dn)[:, None],
-                                      new_hat, theta_hat)
-                msgs += self._wire_deliver(
-                    session, fsched, rf, t, dn_msgs, owner="recipient",
-                    link=1, sched=rf.nominal_recv & rf.online,
-                    ok=rf.delivered_dn, wasted=rf.dn_wasted)
-            else:
-                theta, theta_hat = new_th, new_hat
-                msgs += dn_msgs
-
-        # ---- periodic EF synchronisation (CSER / LIEC) -------------------
-        if spec.sync_period and (t + 1) % spec.sync_period == 0:
-            if session is None:
-                r_up, b_up = spec.uplink.flush(n, d)
-            else:
-                r_up, b_up, fl_msgs = spec.uplink.flush_wire(n, d)
-                if fl_msgs:
-                    r_up = spec.uplink.decode_flush_up(fl_msgs, n, d)
-                msgs += fl_msgs
-            r_dn, b_dn = spec.downlink.flush(n, d)
-            # flush at the aggregator's step size (update.lr), so a
-            # hand-built spec cannot desync the reset from the rounds
-            theta = theta - update.lr * (r_up + r_dn)
-            theta_hat = jnp.tile(theta[None], (n, 1))
-            ul_bits += b_up
-            dl_bits += b_dn
-            if session is not None and b_dn:
-                # The downlink flush re-broadcasts the synced model: n
-                # dense frames of the post-flush theta, n * d * 32 bits
-                # == every stateful downlink's booked flush cost.  The
-                # decoded broadcast drives the trajectory.
-                fd_msgs, theta = self._flush_down_msgs(theta, n, d, b_dn)
-                theta_hat = jnp.tile(theta[None], (n, 1))
-                msgs += fd_msgs
-
-        if faulted:
-            # Book straight from the frames that actually hit the stream
-            # (CTRL overhead rides the uplink direction), so the session
-            # reconcile is exact by construction.
-            from repro.wire import DOWNLINK_DIRS, UPLINK_DIRS
-            ul_r = float(sum(m.payload_bits for m in msgs
-                             if m.direction in UPLINK_DIRS))
-            dl_r = float(sum(m.payload_bits for m in msgs
-                             if m.direction in DOWNLINK_DIRS))
-            rt_r = float(sum(wa.payload_bits
-                             for wa in session.wasted[n_wasted0:]))
-            meter.add_round(ul_r, dl_r, retransmit_bits=rt_r)
-        else:
-            overhead_bits = plan.overhead_bits * n if plan is not None else 0.0
-            meter.add_round(ul_bits, dl_bits, overhead_bits=overhead_bits)
-        return theta, theta_hat
 
     def _wire_deliver(self, session, fsched, rf, t, msgs, *, owner, link,
                       sched, ok, wasted):
@@ -935,8 +875,6 @@ class FLEngine:
         frame iff the retry budget survived.  Returns the delivered
         frames.
         """
-        from repro.wire import Message, WireError
-        from .faults import corrupt_copy
         delivered = []
         for m in msgs:
             cid = getattr(m, owner)
@@ -968,9 +906,11 @@ class FLEngine:
 
     def _check_wire_support(self) -> None:
         spec = self.spec
-        missing = [a for a in ("transmit_wire", "decode_up")
+        up_hooks = ("encode_up", "decode_up") + (
+            ("encode_flush_up",) if spec.sync_period else ())
+        missing = [f"uplink.{a}" for a in up_hooks
                    if not hasattr(spec.uplink, a)]
-        missing += [a for a in ("distribute_wire", "decode_down")
+        missing += [f"downlink.{a}" for a in ("encode_down", "decode_down")
                     if not hasattr(spec.downlink, a)]
         if spec.allocation is not None and not all(
                 hasattr(spec.allocation, a)
@@ -983,15 +923,14 @@ class FLEngine:
         # Fail before any round work: a non-power-of-two n_is books
         # fractional bits per index and would only surface as a
         # WireCapacityError from codecs.index_width mid-run.
-        from repro.wire.codecs import WireCapacityError, index_width
         for role, chan in (("uplink", spec.uplink),
                            ("downlink", spec.downlink)):
             n_is = getattr(chan, "n_is", None)
             if n_is is None:
                 continue
             try:
-                index_width(n_is)
-            except WireCapacityError as e:
+                wcodecs.index_width(n_is)
+            except wcodecs.WireCapacityError as e:
                 raise ValueError(
                     f"spec {spec.name!r} cannot be wire-audited: {role} "
                     f"channel {type(chan).__name__} has n_is={n_is}, "
@@ -999,7 +938,6 @@ class FLEngine:
                     "codecs need a power of two") from e
 
     def _encode_plan_msgs(self, plan, n):
-        from repro.wire import DIR_CTRL, BitWriter, SERVER, Message
         w = BitWriter()
         self.spec.allocation.encode_plan(plan, w)
         payload, nbits = w.getvalue(), w.bits_written
@@ -1008,16 +946,12 @@ class FLEngine:
                 for cid in range(n)]
 
     def _decode_plan_msg(self, msg, d):
-        from repro.wire import BitReader
         r = BitReader(msg.payload, msg.payload_bits)
         plan = self.spec.allocation.decode_plan(r, d)
         r.expect_exhausted()
         return plan
 
     def _flush_down_msgs(self, theta, n, d, b_dn):
-        from repro.wire import DIR_FLUSH_DOWN, BitWriter, BitReader, \
-            SERVER, Message
-        from repro.wire import codecs as wcodecs
         if b_dn != n * d * 32:
             raise ValueError(
                 f"downlink flush books {b_dn} bits; the wire layer only "

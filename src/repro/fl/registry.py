@@ -1,11 +1,45 @@
 """Scheme registry: config -> (uplink, downlink, aggregator) factories.
 
 Every named FL scheme in the repo is a factory returning an
-:class:`~repro.fl.engine.EngineSpec`.  The old string-dispatch if/else
-chains in ``run_bicompfl`` / ``run_baseline`` are gone; adding a scheme is
-one entry here.  New combinations that no seed loop could express -- e.g.
-an MRC uplink with a sign-EF downlink -- are just a hand-rolled EngineSpec
-from the same channel parts (see tests/test_channels.py).
+:class:`~repro.fl.engine.EngineSpec`, run by
+``FLEngine(task, spec).run(...)``; adding a scheme is one entry here.  New
+combinations that no seed loop could express -- e.g. an MRC uplink with a
+sign-EF downlink -- are just a hand-rolled EngineSpec from the same channel
+parts (see tests/test_channels.py).
+
+BiCompFL variants (paper Algorithms 1 & 2, :func:`bicompfl_spec`):
+
+* ``GR``          -- Alg. 1: global shared randomness; the federator *relays*
+                     the clients' MRC indices, every client reconstructs the
+                     identical global model (no extra compression noise).
+* ``GR-Reconst``  -- the suboptimal ablation: the federator reconstructs the
+                     global model and re-transmits it via a second MRC round
+                     (common candidates -> all clients equal estimates).
+* ``PR``          -- Alg. 2: private shared randomness only; per-client MRC
+                     on the downlink; clients hold distinct estimates.
+* ``PR-SplitDL``  -- PR, but the downlink sends each client only a disjoint
+                     1/n slice of the blocks (downlink cost / n).
+
+The uplink/downlink priors are the clients' latest global-model estimates
+(theta_hat), exactly as the paper settles on (lambda = 1).
+:func:`cfl_spec` is BiCompFL-GR in conventional FL: stochastic SignSGD
+deltas conveyed by MRC against the uninformative prior p = 1/2.
+
+Non-stochastic bi-directional baselines (paper Section 4,
+:func:`baseline_spec`; the simplifications are in DESIGN.md section 5):
+
+* fedavg         : dense 32-bit both directions.
+* memsgd         : Stich et al. 2018  -- sign + EF uplink, dense downlink.
+* doublesqueeze  : Tang et al. 2019  -- sign + EF uplink AND downlink.
+* neolithic      : Huang et al. 2022 -- as doublesqueeze with R=2 compression
+                   passes per direction (2 bits/param effective).
+* cser           : Xie et al. 2020   -- sign + EF uplink, dense downlink,
+                   periodic error reset (period 50) adds an amortized sync.
+* liec           : Cheng et al. 2024 -- bidirectional sign with immediate
+                   local error compensation + periodic averaging (period 50).
+* m3             : Gruntkowska et al. 2024 -- TopK(d/n) + EF uplink; downlink
+                   sends each client a *disjoint* 1/n model slice (dense);
+                   clients hold diverging model estimates.
 """
 from __future__ import annotations
 
@@ -165,10 +199,9 @@ def all_schemes(*, n: int, d: int, n_is: int = 16, block: int = 64,
 
     ``task_kind`` is "mask" (probabilistic-mask BiCompFL) or "delta"
     (conventional-FL: the baselines and BiCompFL-CFL).  Factories build a
-    fresh spec per call -- EF channels carry state, so parity sweeps must
-    never share channel instances between runs.  Used by the fused-vs-host
-    parity suite, the bit-accounting property suite and the
-    round-throughput benchmark to enumerate the scheme matrix.
+    fresh spec per call.  Used by the fused-vs-host parity suite, the
+    bit-accounting property suite and the round-throughput benchmark to
+    enumerate the scheme matrix.
 
     ``include_adaptive=True`` appends the KL-driven allocations (the
     Isik-style segment codec on GR and PR, plus the paper's low-complexity

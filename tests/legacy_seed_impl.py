@@ -11,7 +11,8 @@ Do not "fix" or modernise this file.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List
+from dataclasses import dataclass, field
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,10 +21,52 @@ import numpy as np
 from repro.core import mrc
 from repro.core.bernoulli import bern_kl, clip01
 from repro.core.bitmeter import BitMeter
-from repro.core.blocks import AdaptiveAllocation
+from repro.core.blocks import AdaptiveAllocation, FixedAllocation
 from repro.core.quantizers import FLOAT_BITS, sign_compress, topk_bits, topk_compress
-from repro.fl.baselines import BaselineConfig
-from repro.fl.federator import BiCompFLConfig, CFLConfig
+
+
+# The seed loops' run configurations.  tests/test_engine_parity.py builds
+# the engine's spec from the same config through repro.fl.registry.
+
+
+@dataclass
+class BiCompFLConfig:
+    variant: str = "GR"          # GR | GR-Reconst | PR | PR-SplitDL
+    allocation: Any = field(default_factory=lambda: FixedAllocation(256))
+    n_is: int = 256
+    n_ul: int = 1
+    n_dl: Optional[int] = None   # default: n_clients * n_ul (paper)
+    rounds: int = 30
+    seed: int = 0
+    eval_every: int = 1
+    chunk: int = 16              # MRC encode block-chunk (memory knob)
+    logw_fn: Any = None          # optionally the Pallas kernel closure
+    participation: float = 1.0   # fraction of clients per round; < 1 only
+                                 # valid for PR variants
+
+
+@dataclass
+class CFLConfig:
+    n_is: int = 256
+    n_ul: int = 1
+    block_size: int = 16
+    rounds: int = 30
+    server_lr: float = 1.0
+    seed: int = 0
+    eval_every: int = 1
+    chunk: int = 16
+    temperature: str = "auto"    # K: "auto" => mean |delta| per client
+    logw_fn: Any = None
+
+
+@dataclass
+class BaselineConfig:
+    scheme: str = "fedavg"
+    rounds: int = 30
+    server_lr: float = 1.0
+    seed: int = 0
+    eval_every: int = 1
+    reset_period: int = 50   # CSER / LIEC periodic sync
 
 
 def to_blocks(v: jax.Array, size: int) -> jax.Array:

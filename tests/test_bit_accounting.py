@@ -5,8 +5,8 @@ bookkeeping per direction, so the accounting invariants are pinned here for
 **every** channel in ``registry.all_schemes`` (static and adaptive):
 
 * bits are non-negative, finite Python floats under a host (static) plan;
-* the functional core and the object shell report identical bits
-  (``step_up``/``step_down`` vs ``transmit``/``distribute``);
+* the functional core and the wire encoders report identical bits
+  (``step_up``/``step_down`` vs ``encode_up``/``encode_down``);
 * bits are additive across rounds, and ``BitMeter.book_run`` records
   exactly what the per-round channel reports sum to (== an ``add_round``
   loop, including per-round overhead sequences);
@@ -70,7 +70,7 @@ def _ctx(spec, payload, priors, active=None):
 
 
 def _one_round(spec, ctx, payload, priors, theta):
-    """Functional-core round; returns (ul_bits, dl_bits, shell bits pair)."""
+    """Functional-core round; returns (ul_bits, dl_bits, wire bits pair)."""
     up_s = spec.uplink.init_up_state(N, D)
     up_out, ul_bits, _ = spec.uplink.step_up(ctx, up_s, payload, priors)
     update = spec.aggregator(ctx, theta, up_out)
@@ -78,14 +78,11 @@ def _one_round(spec, ctx, payload, priors, theta):
     dn_s = spec.downlink.init_down_state(N, D)
     res, _ = spec.downlink.step_down(ctx, dn_s, update, theta, theta_hat)
 
-    # object shell must report the identical bits
-    for chan in (spec.uplink, spec.downlink):
-        reset = getattr(chan, "reset", None)
-        if reset is not None:
-            reset()
-    _, ul_shell = spec.uplink.transmit(ctx, payload, priors)
-    res_shell = spec.downlink.distribute(ctx, update, theta, theta_hat)
-    return ul_bits, res.bits, ul_shell, res_shell.bits
+    # the wire encoders must report the identical bits
+    _, ul_wire, _, up_msgs = spec.uplink.encode_up(ctx, up_s, payload, priors)
+    res_wire, _, _ = spec.downlink.encode_down(ctx, dn_s, update, theta,
+                                               theta_hat, up_msgs)
+    return ul_bits, res.bits, ul_wire, res_wire.bits
 
 
 @pytest.mark.parametrize("name,kind,factory", SCHEMES, ids=SCHEME_IDS)
@@ -93,12 +90,12 @@ def test_bits_nonneg_finite_static_float(name, kind, factory):
     spec = factory()
     payload, priors, theta = _round_inputs(kind)
     ctx = _ctx(spec, payload, priors)
-    ul, dl, ul_shell, dl_shell = _one_round(spec, ctx, payload, priors, theta)
+    ul, dl, ul_wire, dl_wire = _one_round(spec, ctx, payload, priors, theta)
     for b in (ul, dl):
         # host-plan contract: a plain, data-independent Python number
         assert isinstance(b, (int, float)), (name, type(b))
         assert math.isfinite(b) and b >= 0.0, (name, b)
-    assert ul_shell == ul and dl_shell == dl, name
+    assert ul_wire == ul and dl_wire == dl, name
     if ctx.plan is not None:
         oh = float(ctx.plan.overhead_bits)
         assert math.isfinite(oh) and oh >= 0.0

@@ -33,13 +33,26 @@ def _payload(n=N, d=D):
     return jax.random.normal(KEY, (n, d))
 
 
+def _up(chan, ctx, payload, priors):
+    """One uplink step from the channel's initial state."""
+    state = chan.init_up_state(ctx.n_clients, ctx.d)
+    return chan.step_up(ctx, state, payload, priors)
+
+
+def _down(chan, ctx, update, theta, theta_hat):
+    """One downlink step from the channel's initial state."""
+    state = chan.init_down_state(ctx.n_clients, ctx.d)
+    res, _ = chan.step_down(ctx, state, update, theta, theta_hat)
+    return res
+
+
 class TestMRCChannels:
     def test_fixed_uplink_bits_and_shape(self):
         ctx = _ctx()
         q = jax.random.uniform(KEY, (N, D), minval=0.2, maxval=0.8)
         p = jnp.full((N, D), 0.5)
         chan = ch.MRCFixedChannel(n_is=16, n_samples=2, shared=True)
-        q_hat, bits = chan.transmit(ctx, q, p)
+        q_hat, bits, _ = _up(chan, ctx, q, p)
         assert q_hat.shape == (N, D)
         assert bits == N * 2 * ctx.plan.n_blocks * math.log2(16)
         # estimates are means of {0,1} samples
@@ -50,7 +63,7 @@ class TestMRCChannels:
         q = jax.random.uniform(KEY, (2, D), minval=0.2, maxval=0.8)
         p = jnp.full((2, D), 0.5)
         chan = ch.MRCFixedChannel(n_is=16, n_samples=1, shared=False)
-        q_hat, bits = chan.transmit(ctx, q, p)
+        q_hat, bits, _ = _up(chan, ctx, q, p)
         assert q_hat.shape == (2, D)
         assert bits == 2 * ctx.plan.n_blocks * math.log2(16)
 
@@ -59,7 +72,7 @@ class TestMRCChannels:
         theta_hat = jnp.full((N, D), 0.5)
         update = ch.ServerUpdate(theta=jax.random.uniform(KEY, (D,)))
         chan = ch.MRCPrivateDownlink(n_is=16, n_samples=2)
-        res = chan.distribute(ctx, update, jnp.zeros(D), theta_hat)
+        res = _down(chan, ctx, update, jnp.zeros(D), theta_hat)
         assert res.bits == 2 * 2 * ctx.plan.n_blocks * math.log2(16)
         th = np.asarray(res.theta_hat)
         np.testing.assert_array_equal(th[0], 0.5 * np.ones(D))
@@ -72,8 +85,8 @@ class TestMRCChannels:
         full = ch.MRCPrivateDownlink(n_is=16, n_samples=4)
         split = ch.SplitBlockDownlink(n_is=16, n_samples=4)
         theta_hat = jnp.full((N, D), 0.5)
-        rf = full.distribute(ctx, update, jnp.zeros(D), theta_hat)
-        rs = split.distribute(ctx, update, jnp.zeros(D), theta_hat)
+        rf = _down(full, ctx, update, jnp.zeros(D), theta_hat)
+        rs = _down(split, ctx, update, jnp.zeros(D), theta_hat)
         # each client receives ceil(B/n) of the B blocks
         max_len = -(-ctx.plan.n_blocks // N)
         assert rs.bits == N * 4 * max_len * math.log2(16)
@@ -83,7 +96,7 @@ class TestMRCChannels:
         ctx = _ctx()
         update = ch.ServerUpdate(theta=jnp.full((D,), 0.25))
         chan = ch.IndexRelayDownlink(n_is=16, n_samples=3, side_info_bits=32)
-        res = chan.distribute(ctx, update, jnp.zeros(D), jnp.zeros((N, D)))
+        res = _down(chan, ctx, update, jnp.zeros(D), jnp.zeros((N, D)))
         expect = N * (N - 1) * (3 * ctx.plan.n_blocks * math.log2(16) + 32)
         assert res.bits == expect
         np.testing.assert_array_equal(np.asarray(res.theta_hat),
@@ -93,11 +106,11 @@ class TestMRCChannels:
 class TestBaselineChannels:
     def test_dense_bits(self):
         ctx = _ctx()
-        out, bits = ch.DenseChannel().transmit(ctx, _payload(), None)
+        out, bits, _ = _up(ch.DenseChannel(), ctx, _payload(), None)
         assert bits == N * D * FLOAT_BITS
-        res = ch.DenseChannel().distribute(
-            ctx, ch.ServerUpdate(theta=jnp.ones(D)), jnp.zeros(D),
-            jnp.zeros((N, D)))
+        res = _down(ch.DenseChannel(), ctx,
+                    ch.ServerUpdate(theta=jnp.ones(D)), jnp.zeros(D),
+                    jnp.zeros((N, D)))
         assert res.bits == N * D * FLOAT_BITS
 
     @pytest.mark.parametrize("passes", [1, 2])
@@ -105,38 +118,37 @@ class TestBaselineChannels:
         ctx = _ctx()
         chan = ch.SignEFChannel(passes=passes)
         v = _payload()
-        c, bits = chan.transmit(ctx, v, None)
+        c, bits, e = _up(chan, ctx, v, None)
         assert bits == N * passes * (D + FLOAT_BITS)
         # EF invariant: compressed + residual == input (+ zero initial memory)
-        np.testing.assert_allclose(np.asarray(c + chan._e), np.asarray(v),
+        np.testing.assert_allclose(np.asarray(c + e), np.asarray(v),
                                    rtol=1e-6, atol=1e-6)
 
     def test_sign_ef_flush_returns_mean_residual(self):
         ctx = _ctx()
         chan = ch.SignEFChannel()
         v = _payload()
-        c, _ = chan.transmit(ctx, v, None)
+        c, _, e = _up(chan, ctx, v, None)
         resid = np.asarray(jnp.mean(v - c, axis=0))
-        r, bits = chan.flush(N, D)
+        r, bits, e = chan.flush_step(e, N, D)
         np.testing.assert_allclose(np.asarray(r), resid, rtol=1e-6, atol=1e-6)
         assert bits == N * D * FLOAT_BITS
         # memory cleared
-        np.testing.assert_array_equal(np.asarray(chan._e), np.zeros((N, D)))
+        np.testing.assert_array_equal(np.asarray(e), np.zeros((N, D)))
 
     def test_topk_ef_bits(self):
         ctx = _ctx()
         k = D // N
         chan = ch.TopKEFChannel(k=k)
-        c, bits = chan.transmit(ctx, _payload(), None)
+        c, bits, _ = _up(chan, ctx, _payload(), None)
         assert bits == N * topk_bits(D, k)
         assert int(jnp.sum(c[0] != 0)) <= k
 
     def test_slice_downlink_disjoint(self):
         ctx = _ctx()
         th = jnp.arange(D, dtype=jnp.float32)
-        res = ch.SliceDownlink().distribute(
-            ctx, ch.ServerUpdate(theta=th), jnp.zeros(D),
-            jnp.full((N, D), -1.0))
+        res = _down(ch.SliceDownlink(), ctx, ch.ServerUpdate(theta=th),
+                    jnp.zeros(D), jnp.full((N, D), -1.0))
         assert res.bits == N * (D / N) * FLOAT_BITS
         got = np.asarray(res.theta_hat)
         k = D // N
@@ -149,7 +161,7 @@ class TestBaselineChannels:
     def test_ef_uplink_rejects_partial_participation(self):
         ctx = _ctx(active=[0, 1])
         with pytest.raises(ValueError):
-            ch.SignEFChannel().transmit(ctx, _payload(2), None)
+            _up(ch.SignEFChannel(), ctx, _payload(2), None)
 
 
 def test_engine_resets_ef_state_between_runs():

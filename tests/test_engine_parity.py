@@ -2,7 +2,8 @@
 seed's monolithic loops bit-for-bit.
 
 Each case runs the vendored legacy loop (tests/legacy_seed_impl.py) and the
-new engine-backed wrapper on the same tiny synthetic task and asserts equal
+engine, with the scheme built from the same run config by
+``repro.fl.registry``, on the same tiny synthetic task and asserts equal
 histories (accuracy floats, cumulative bits), meters, and final model /
 client-estimate arrays -- exact equality, no tolerances."""
 import jax
@@ -11,15 +12,45 @@ import numpy as np
 import pytest
 
 from repro.core.blocks import AdaptiveAllocation, FixedAllocation
-from repro.fl.baselines import BaselineConfig, run_baseline
+from repro.fl import registry
 from repro.fl.data import make_synthetic, partition_iid
-from repro.fl.federator import (BiCompFLConfig, CFLConfig, run_bicompfl,
-                                run_bicompfl_cfl)
+from repro.fl.engine import FLEngine
 from repro.fl.nets import make_mlp
 from repro.fl.tasks import make_cfl_task, make_mask_task
 
-from legacy_seed_impl import (run_baseline_legacy, run_bicompfl_cfl_legacy,
+from legacy_seed_impl import (BaselineConfig, BiCompFLConfig, CFLConfig,
+                              run_baseline_legacy, run_bicompfl_cfl_legacy,
                               run_bicompfl_legacy)
+
+
+def run_bicompfl(task, shards, cfg):
+    """The engine on a legacy BiCompFL config (paper n_dl = n * n_ul)."""
+    n = int(shards.x.shape[0])
+    n_dl = cfg.n_dl if cfg.n_dl is not None else n * cfg.n_ul
+    spec = registry.bicompfl_spec(
+        cfg.variant, allocation=cfg.allocation, n_is=cfg.n_is, n_ul=cfg.n_ul,
+        n_dl=n_dl, chunk=cfg.chunk, logw_fn=cfg.logw_fn,
+        participation=cfg.participation)
+    return FLEngine(task, spec).run(shards, rounds=cfg.rounds, seed=cfg.seed,
+                                    eval_every=cfg.eval_every)
+
+
+def run_bicompfl_cfl(task, theta0, shards, cfg):
+    spec = registry.cfl_spec(n_is=cfg.n_is, n_ul=cfg.n_ul,
+                             block_size=cfg.block_size,
+                             server_lr=cfg.server_lr, chunk=cfg.chunk,
+                             logw_fn=cfg.logw_fn)
+    return FLEngine(task, spec).run(shards, theta0, rounds=cfg.rounds,
+                                    seed=cfg.seed, eval_every=cfg.eval_every)
+
+
+def run_baseline(task, theta0, shards, cfg):
+    spec = registry.baseline_spec(cfg.scheme, n=int(shards.x.shape[0]),
+                                  d=int(theta0.shape[0]),
+                                  server_lr=cfg.server_lr,
+                                  reset_period=cfg.reset_period)
+    return FLEngine(task, spec).run(shards, theta0, rounds=cfg.rounds,
+                                    seed=cfg.seed, eval_every=cfg.eval_every)
 
 
 @pytest.fixture(scope="module")

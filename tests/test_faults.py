@@ -285,6 +285,27 @@ def test_wire_faulted_audit_reconciles_and_matches_booking(mask_setup):
         == plain["faults"]["summary"]["retransmits_total"]
 
 
+@pytest.mark.parametrize("name,kind,factory", FAULT_MATRIX,
+                         ids=[s[0] for s in FAULT_MATRIX])
+def test_wire_faulted_audit_matches_staged_trajectory(mask_setup, cfl_setup,
+                                                      name, kind, factory):
+    """The faulted wire round carries EF rows, theta_hat rows and all-fail
+    discards exactly as the staged host round: same trajectory, bit for
+    bit, with the decoded frames driving it."""
+    task, shards, theta0 = _setup_for(kind, mask_setup, cfl_setup)
+    plain = FLEngine(task, factory()).run(shards, theta0, rounds=3, seed=7,
+                                          mode="host", faults=PLAN)
+    wired = FLEngine(task, factory()).run(shards, theta0, rounds=3, seed=7,
+                                          mode="host", wire="audit",
+                                          faults=PLAN)
+    assert plain["faults"]["summary"]["faulty_rounds"] > 0
+    np.testing.assert_array_equal(np.asarray(wired["theta"]),
+                                  np.asarray(plain["theta"]))
+    np.testing.assert_array_equal(np.asarray(wired["theta_hat"]),
+                                  np.asarray(plain["theta_hat"]))
+    assert wired["history"] == plain["history"], name
+
+
 # ---------------------------------------------------------------------------
 # Crash-safe resume: killed at a checkpoint == uninterrupted.
 # ---------------------------------------------------------------------------

@@ -8,10 +8,22 @@ import numpy as np
 import pytest
 
 from repro.core.blocks import AdaptiveAvgAllocation, FixedAllocation
+from repro.fl import registry
 from repro.fl.data import make_synthetic, partition_dirichlet, partition_iid
-from repro.fl.federator import BiCompFLConfig, CFLConfig, run_bicompfl, run_bicompfl_cfl
+from repro.fl.engine import FLEngine
 from repro.fl.nets import make_cnn, make_mlp
 from repro.fl.tasks import make_cfl_task, make_mask_task
+
+
+def run_bicompfl(task, shards, variant, *, rounds, n_is, allocation=None,
+                 participation=1.0):
+    """BiCompFL through the registry, one uplink sample and the paper's
+    n_dl = n * n_ul downlink samples."""
+    n, n_ul = int(shards.x.shape[0]), 1
+    spec = registry.bicompfl_spec(
+        variant, allocation=allocation or FixedAllocation(256), n_is=n_is,
+        n_ul=n_ul, n_dl=n * n_ul, participation=participation)
+    return FLEngine(task, spec).run(shards, rounds=rounds)
 
 
 @pytest.fixture(scope="module")
@@ -28,9 +40,8 @@ def small_setup():
 @pytest.mark.parametrize("variant", ["GR", "GR-Reconst", "PR", "PR-SplitDL"])
 def test_variants_run_and_learn(small_setup, variant):
     task, shards = small_setup
-    cfg = BiCompFLConfig(variant=variant, rounds=4, n_is=32,
-                         allocation=FixedAllocation(128))
-    out = run_bicompfl(task, shards, cfg)
+    out = run_bicompfl(task, shards, variant, rounds=4, n_is=32,
+                       allocation=FixedAllocation(128))
     assert np.isfinite(out["final_acc"])
     # GR/PR learn fast; the Reconst/SplitDL ablations carry extra MRC noise.
     # PR lands at 0.393 under these tiny settings (identical in the seed
@@ -44,9 +55,8 @@ def test_gr_uplink_bpp_matches_formula(small_setup):
     """GR-Fixed: uplink bpp/round == n_blocks*log2(n_is) / d (paper Table 5)."""
     task, shards = small_setup
     n, n_is, bs = 4, 32, 128
-    cfg = BiCompFLConfig(variant="GR", rounds=2, n_is=n_is,
-                         allocation=FixedAllocation(bs))
-    out = run_bicompfl(task, shards, cfg)
+    out = run_bicompfl(task, shards, "GR", rounds=2, n_is=n_is,
+                       allocation=FixedAllocation(bs))
     d = task.d
     n_blocks = -(-d // bs)
     expect_ul = n_blocks * math.log2(n_is) / d           # per client per round
@@ -58,30 +68,27 @@ def test_gr_uplink_bpp_matches_formula(small_setup):
 
 def test_splitdl_downlink_cheaper(small_setup):
     task, shards = small_setup
-    base = BiCompFLConfig(variant="PR", rounds=2, n_is=32,
-                          allocation=FixedAllocation(128))
-    split = BiCompFLConfig(variant="PR-SplitDL", rounds=2, n_is=32,
-                           allocation=FixedAllocation(128))
-    out_b = run_bicompfl(task, shards, base)
-    out_s = run_bicompfl(task, shards, split)
+    out_b = run_bicompfl(task, shards, "PR", rounds=2, n_is=32,
+                         allocation=FixedAllocation(128))
+    out_s = run_bicompfl(task, shards, "PR-SplitDL", rounds=2, n_is=32,
+                         allocation=FixedAllocation(128))
     assert out_s["meter"]["downlink_bpp"] < out_b["meter"]["downlink_bpp"] / 2
 
 
 def test_broadcast_bpp_only_helps_gr(small_setup):
     """bpp(BC) divides the GR downlink by n; PR cannot profit (paper App. I)."""
     task, shards = small_setup
-    gr = run_bicompfl(task, shards, BiCompFLConfig(variant="GR", rounds=2, n_is=32))
-    pr = run_bicompfl(task, shards, BiCompFLConfig(variant="PR", rounds=2, n_is=32))
+    gr = run_bicompfl(task, shards, "GR", rounds=2, n_is=32)
+    pr = run_bicompfl(task, shards, "PR", rounds=2, n_is=32)
     assert gr["meter"]["bpp_bc"] < gr["meter"]["bpp"]
     assert abs(pr["meter"]["bpp_bc"] - pr["meter"]["bpp"]) < 1e-9
 
 
 def test_adaptive_avg_allocation_runs(small_setup):
     task, shards = small_setup
-    cfg = BiCompFLConfig(variant="GR", rounds=3, n_is=32,
-                         allocation=AdaptiveAvgAllocation(min_block=64,
-                                                          max_block=512))
-    out = run_bicompfl(task, shards, cfg)
+    out = run_bicompfl(task, shards, "GR", rounds=3, n_is=32,
+                       allocation=AdaptiveAvgAllocation(min_block=64,
+                                                        max_block=512))
     assert np.isfinite(out["final_acc"])
 
 
@@ -92,7 +99,7 @@ def test_noniid_dirichlet_partition_runs(small_setup):
     net = make_mlp(in_dim=64, widths=(64,), signed_constant=True)
     task = make_mask_task(net, jax.random.fold_in(k, 2), test.x, test.y,
                           local_epochs=1)
-    out = run_bicompfl(task, shards, BiCompFLConfig(variant="GR", rounds=3, n_is=32))
+    out = run_bicompfl(task, shards, "GR", rounds=3, n_is=32)
     assert np.isfinite(out["final_acc"])
 
 
@@ -104,8 +111,8 @@ def test_cfl_stochastic_sign(small_setup):
     net = make_mlp(in_dim=64, widths=(64,))
     task, theta0 = make_cfl_task(net, jax.random.fold_in(k, 2), test.x, test.y,
                                  local_epochs=5, batch_size=32, local_lr=3e-3)
-    out = run_bicompfl_cfl(task, theta0, shards,
-                           CFLConfig(rounds=4, server_lr=1.0))
+    out = FLEngine(task, registry.cfl_spec(server_lr=1.0)).run(
+        shards, theta0, rounds=4)
     assert np.isfinite(out["final_acc"])
     assert out["max_acc"] > 0.5
     # bitrate: log2(n_is)/block bits per param per direction (order check)
@@ -115,7 +122,7 @@ def test_cfl_stochastic_sign(small_setup):
 def test_gr_all_clients_synchronized(small_setup):
     """GR: every client ends each round with the identical estimate."""
     task, shards = small_setup
-    out = run_bicompfl(task, shards, BiCompFLConfig(variant="GR", rounds=2, n_is=16))
+    out = run_bicompfl(task, shards, "GR", rounds=2, n_is=16)
     th = np.asarray(out["theta_hat"])
     for i in range(1, th.shape[0]):
         np.testing.assert_array_equal(th[0], th[i])
@@ -125,22 +132,20 @@ def test_pr_partial_participation(small_setup):
     """PR with 50% participation per round: runs, learns, bills only the
     active cohort; GR refuses (incompatible with global randomness)."""
     task, shards = small_setup
-    cfg = BiCompFLConfig(variant="PR", rounds=4, n_is=32, participation=0.5,
-                         allocation=FixedAllocation(128))
-    out = run_bicompfl(task, shards, cfg)
+    out = run_bicompfl(task, shards, "PR", rounds=4, n_is=32,
+                       participation=0.5, allocation=FixedAllocation(128))
     assert np.isfinite(out["final_acc"])
-    full = run_bicompfl(task, shards,
-                        BiCompFLConfig(variant="PR", rounds=4, n_is=32,
-                                       allocation=FixedAllocation(128)))
+    full = run_bicompfl(task, shards, "PR", rounds=4, n_is=32,
+                        allocation=FixedAllocation(128))
     assert out["meter"]["bpp"] < full["meter"]["bpp"] * 0.75
     with pytest.raises(ValueError):
-        run_bicompfl(task, shards,
-                     BiCompFLConfig(variant="GR", rounds=1, participation=0.5))
+        run_bicompfl(task, shards, "GR", rounds=1, n_is=256,
+                     participation=0.5)
 
 
 def test_pr_clients_diverge(small_setup):
     """PR: without shared candidates the clients' estimates differ."""
     task, shards = small_setup
-    out = run_bicompfl(task, shards, BiCompFLConfig(variant="PR", rounds=2, n_is=16))
+    out = run_bicompfl(task, shards, "PR", rounds=2, n_is=16)
     th = np.asarray(out["theta_hat"])
     assert not np.array_equal(th[0], th[1])

@@ -191,11 +191,12 @@ def test_adaptive_fused_supported_no_fallback(mask_setup):
 
 
 def test_non_functional_channel_still_host_only(mask_setup):
-    """Revised eligibility: only non-functional channels force the host loop
-    (plus allocations exposing neither a static plan nor the bucket API)."""
+    """A channel without the explicit-state protocol is refused by ``run``
+    in every mode, naming what it lacks; only an allocation exposing
+    neither a static plan nor the bucket API forces the host loop."""
     task, shards = mask_setup
 
-    class LegacyOnlyDownlink:  # object shell without the functional core
+    class StatefulOnlyDownlink:  # no init_down_state/step_down/flush_step
         broadcast_shareable = True
 
         def distribute(self, ctx, update, theta, theta_hat):
@@ -203,8 +204,10 @@ def test_non_functional_channel_still_host_only(mask_setup):
 
     spec = registry.bicompfl_spec("GR", allocation=FixedAllocation(64),
                                   n_is=16, n_dl=3)
-    spec.downlink = LegacyOnlyDownlink()
-    assert not FLEngine(task, spec).fused_supported()
+    spec.downlink = StatefulOnlyDownlink()
+    for mode in ("auto", "host", "fused"):
+        with pytest.raises(ValueError, match=r"downlink\.step_down"):
+            FLEngine(task, spec).run(shards, rounds=1, seed=1, mode=mode)
 
     class NoBucketAdaptive:  # data-dependent plan without the bucket API
         static_plan = False

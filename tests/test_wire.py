@@ -38,7 +38,8 @@ from repro.core.bernoulli import bern_kl, clip01
 from repro.core.bitmeter import BitMeter, ReconcileError
 from repro.core.quantizers import sign_bits, topk_bits
 from repro.fl import registry
-from repro.fl.channels import BlockPlan, RoundContext, WireEnv
+from repro.fl.channels import (BlockPlan, RoundContext, WireEnv,
+                               decode_flush_up)
 from repro.fl.data import make_synthetic, partition_iid
 from repro.fl.engine import EngineSpec, FLEngine, MeanDeltaAggregator
 from repro.fl.nets import make_mlp
@@ -416,13 +417,6 @@ def _ctx(spec, payload, priors):
                         active=np.arange(N), plan=plan)
 
 
-def _reset(spec):
-    for chan in (spec.uplink, spec.downlink):
-        reset = getattr(chan, "reset", None)
-        if reset is not None:
-            reset()
-
-
 def _bits_close(stream_bits, booked):
     return math.isclose(stream_bits, booked,
                         rel_tol=RECONCILE_REL_TOL, abs_tol=RECONCILE_TOL_BITS)
@@ -435,22 +429,31 @@ def test_channel_hooks_lossless_and_stream_matches_booked(name, kind, factory):
     ctx = _ctx(spec, payload, priors)
     theta_hat = jnp.tile(theta[None], (N, 1))
 
+    up_s = spec.uplink.init_up_state(N, D)
+    dn_s = spec.downlink.init_down_state(N, D)
+
     # direct reference round
-    up_direct, ul_direct = spec.uplink.transmit(ctx, payload, priors)
+    up_direct, ul_direct, up_s1 = spec.uplink.step_up(ctx, up_s, payload,
+                                                      priors)
     update = spec.aggregator(ctx, theta, up_direct)
-    th_d, thh_d, dl_direct = spec.downlink.distribute(ctx, update, theta,
-                                                      theta_hat)
-    _reset(spec)
+    res_d, dn_s1 = spec.downlink.step_down(ctx, dn_s, update, theta,
+                                           theta_hat)
+    th_d, thh_d, dl_direct = res_d
 
     # wire round: encode -> decode drives everything
-    _, ul_wire, up_msgs = spec.uplink.transmit_wire(ctx, payload, priors)
+    _, ul_wire, up_s2, up_msgs = spec.uplink.encode_up(ctx, up_s, payload,
+                                                       priors)
     up_dec = spec.uplink.decode_up(ctx, up_msgs, priors)
     np.testing.assert_array_equal(np.asarray(up_dec), np.asarray(up_direct))
     assert ul_wire == ul_direct, name
+    for a, b in zip(jax.tree.leaves(up_s2), jax.tree.leaves(up_s1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     update_w = spec.aggregator(ctx, theta, up_dec)
-    _, dn_msgs = spec.downlink.distribute_wire(ctx, update_w, theta,
-                                               theta_hat, up_msgs)
+    _, dn_s2, dn_msgs = spec.downlink.encode_down(ctx, dn_s, update_w, theta,
+                                                  theta_hat, up_msgs)
+    for a, b in zip(jax.tree.leaves(dn_s2), jax.tree.leaves(dn_s1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     env = WireEnv(uplink=spec.uplink, aggregator=spec.aggregator,
                   priors=priors, up_msgs=up_msgs, update=update_w)
     th_w, thh_w, dl_wire = spec.downlink.decode_down(ctx, dn_msgs, theta,
@@ -491,19 +494,20 @@ def test_plan_header_roundtrip_at_booked_overhead(name, kind, factory):
 
 @pytest.mark.parametrize("scheme", ["cser", "liec"])
 def test_flush_wire_matches_flush(scheme):
-    mk = lambda: registry.baseline_spec(scheme, n=N, d=D, reset_period=2)
+    spec = registry.baseline_spec(scheme, n=N, d=D, reset_period=2)
     payload, priors, theta = _round_inputs("delta")
-    s1, s2 = mk(), mk()
-    ctx1, ctx2 = _ctx(s1, payload, priors), _ctx(s2, payload, priors)
-    s1.uplink.transmit(ctx1, payload, priors)  # populate the EF memories
-    s2.uplink.transmit(ctx2, payload, priors)
-    r1, b1 = s1.uplink.flush(N, D)
-    _, b2, msgs = s2.uplink.flush_wire(N, D)
+    ctx = _ctx(spec, payload, priors)
+    # one round populates the EF memories
+    _, _, e = spec.uplink.step_up(ctx, spec.uplink.init_up_state(N, D),
+                                  payload, priors)
+    r1, b1, e1 = spec.uplink.flush_step(e, N, D)
+    _, b2, e2, msgs = spec.uplink.encode_flush_up(e, N, D)
     assert b2 == b1
+    np.testing.assert_array_equal(np.asarray(e2), np.asarray(e1))
     assert len(msgs) == N
     assert all(m.direction == DIR_FLUSH_UP for m in msgs)
     assert _bits_close(sum(m.payload_bits for m in msgs), b1)
-    dec = s2.uplink.decode_flush_up(msgs, N, D)
+    dec = decode_flush_up(msgs, N, D)
     np.testing.assert_array_equal(np.asarray(dec), np.asarray(r1))
 
 
